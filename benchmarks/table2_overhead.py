@@ -22,9 +22,9 @@ benchmark-regression gate (benchmarks/gate.py) protects.
 
 The ``first_decision`` section (stamped before any other section warms
 a kernel) times the process's cold first batched placement — the XLA
-compile, or a persistent-cache read when ``REPRO_JIT_CACHE=1``
+compile, or a read from the persistent compilation cache
 (repro.core.jitcache) — against the in-process warm repeat, with the
-jit-cache status alongside so the two regimes are distinguishable.
+jit-cache status alongside.
 
 The ``batched_lb`` section does the same for the D-Rex LB kernel
 (repro.core.lb_kernel) at ``n_nodes`` and again at ``greedy_nodes``
@@ -74,11 +74,11 @@ def _first_decision(n_nodes: int, batch: int, lines: list[str]) -> dict:
     section jits a kernel, while the process is genuinely cold).
 
     Cold = the process's first batched placement, which pays the XLA
-    compile — from source, or from the persistent disk cache when
-    ``REPRO_JIT_CACHE=1`` (repro.core.jitcache) and a previous process
-    already compiled the same bucketed signature.  Warm = the same call
-    on a fresh engine, served by the in-process jit cache.  The stamped
-    ``jit_cache`` status says which regime the cold number measured.
+    compile — from source, or from the persistent disk cache
+    (repro.core.jitcache) when a previous process already compiled the
+    same bucketed signature.  Warm = the same call on a fresh engine,
+    served by the in-process jit cache.  The stamped ``jit_cache``
+    status gives the cache directory and its entry count.
     """
     items = [DataItem(i, 117.0, float(i), 365.0, 0.999) for i in range(batch)]
     t0 = time.perf_counter()

@@ -146,9 +146,7 @@ class Scheduler:
         return -1 if mp is None else mp
 
 
-def _kernel_dispatch(
-    scheduler, kernel_ok: bool, cluster: ClusterView, batch: int
-) -> bool:
+def _kernel_dispatch(scheduler, cluster: ClusterView, batch: int) -> bool:
     """The one kernel/scalar dispatch rule for kernel-backed schedulers:
     a single item needs at least ``KERNEL_MIN_NODES`` live nodes for the
     kernel to beat numpy dispatch; batches of >= 4 items amortize
@@ -157,7 +155,7 @@ def _kernel_dispatch(
     only wins batched on large clusters).  Setting both to 0 forces the
     kernel everywhere (the equivalence tests do).  Boundary pinned by
     tests/test_kernel_dispatch_boundary.py."""
-    if not (scheduler.use_kernel and kernel_ok):
+    if not scheduler.use_kernel:
         return False
     live = int(np.count_nonzero(cluster.alive))
     if batch >= 4:
@@ -168,25 +166,18 @@ def _kernel_dispatch(
 class _KernelSchedulerMixin:
     """Kernel/scalar dispatch shared by the kernel-backed prefix
     schedulers (the greedys on :mod:`repro.core.greedy_kernel`, D-Rex LB
-    on :mod:`repro.core.lb_kernel`).  Concrete classes set
-    ``KERNEL_MODULE`` and provide the scalar oracle (``_place_scalar``),
-    the batched kernel path (``_place_kernel``) and the
-    ``KERNEL_MIN_NODES`` crossover."""
+    on :mod:`repro.core.lb_kernel`).  Concrete classes provide the
+    scalar oracle (``_place_scalar``), the batched kernel path
+    (``_place_kernel``) and the ``KERNEL_MIN_NODES`` crossover."""
 
-    #: set to False to force the scalar numpy oracle even when jax is
-    #: present.
+    #: set to False to force the scalar numpy oracle.
     use_kernel = True
     #: live-node crossover for batched (>= 4 item) dispatch; 0 = batches
     #: always use the kernel (see :func:`_kernel_dispatch`).
     KERNEL_MIN_NODES_BATCH = 0
-    #: module providing ``kernel_available()`` for this scheduler's
-    #: vectorized path; set by concrete classes.
-    KERNEL_MODULE = None
 
     def _kernel_wins(self, cluster: ClusterView, batch: int) -> bool:
-        return _kernel_dispatch(
-            self, self.KERNEL_MODULE.kernel_available(), cluster, batch
-        )
+        return _kernel_dispatch(self, cluster, batch)
 
     def place(
         self, item: DataItem, cluster: ClusterView, ctx=None, constraints=None
@@ -247,14 +238,13 @@ class GreedyMinStorage(_KernelSchedulerMixin, Scheduler):
     closed form for every N at once wherever the bw-sorted prefix fits
     the chunk, finishing capacity-tight rows with the same
     :meth:`_fixed_point_row` the oracle runs.  ``place`` uses the kernel
-    when jax is importable and the cluster clears ``KERNEL_MIN_NODES``
+    when the cluster clears ``KERNEL_MIN_NODES``
     (batches of >= 4 items always do); ``place_batch`` vmaps it over many
     items sharing a snapshot.  Decisions are bit-for-bit equivalent and
     pinned by tests/test_greedy_vectorized.py.
     """
 
     name = "greedy_min_storage"
-    KERNEL_MODULE = greedy_kernel
     #: below this many live nodes a single-item kernel call is dispatch-
     #: bound and the scalar oracle wins; batches of >= 4 items amortize
     #: dispatch and use the kernel regardless (measured crossover,
@@ -457,7 +447,6 @@ class GreedyLeastUsed(_KernelSchedulerMixin, Scheduler):
     """
 
     name = "greedy_least_used"
-    KERNEL_MODULE = greedy_kernel
     #: the scalar scan stops at the first feasible N (typically < 10), so
     #: a single-item kernel call is dispatch-bound at any realistic
     #: cluster size (measured: the scalar oracle wins even at 500 nodes);
@@ -655,7 +644,6 @@ class DRexLB(_KernelSchedulerMixin, Scheduler):
     """
 
     name = "drex_lb"
-    KERNEL_MODULE = lb_kernel
     #: below this many live nodes a single-item kernel call is dispatch-
     #: bound and the (vectorized-numpy) scalar oracle wins — LB's oracle
     #: is grid-shaped too, so the single-item crossover sits much higher
@@ -957,9 +945,9 @@ class DRexSC(Scheduler):
       :meth:`place_batch` vmaps it over many items sharing a cluster
       snapshot (consumed by ``PlacementEngine.place_many``).
 
-    ``place`` uses the kernel when jax is importable and the cluster is
-    large enough for the kernel to win over numpy dispatch
-    (``KERNEL_MIN_NODES``; batches of >= 4 items always use it); set
+    ``place`` uses the kernel when the cluster is large enough for the
+    kernel to win over numpy dispatch (``KERNEL_MIN_NODES``; batches of
+    >= 4 items always use it); set
     ``use_kernel = False`` to force the oracle.  Decisions are
     equivalent by construction and pinned by tests/test_sc_vectorized.py.
 
@@ -983,8 +971,7 @@ class DRexSC(Scheduler):
     #: freest-M prefix — exact by the start-major enumeration order.
     #: False disables (the scale benchmark times both paths).
     use_prefilter = True
-    #: set to False to force the scalar numpy oracle even when jax is
-    #: present.
+    #: set to False to force the scalar numpy oracle.
     use_kernel = True
     #: below this many live nodes a single-item kernel call is dispatch-
     #: bound and the numpy oracle wins; batches amortize dispatch and use
@@ -1043,9 +1030,7 @@ class DRexSC(Scheduler):
         return self._sat_tracker.f_base_sum(cluster, smin)
 
     def _kernel_wins(self, cluster: ClusterView, batch: int) -> bool:
-        return _kernel_dispatch(
-            self, sc_kernel.kernel_available(), cluster, batch
-        )
+        return _kernel_dispatch(self, cluster, batch)
 
     def place(
         self, item: DataItem, cluster: ClusterView, ctx=None, constraints=None

@@ -48,11 +48,11 @@ inputs from the oracle's own :class:`ParityFrontier` (the same
 equivalence-by-construction move as this module's RNA rows; see that
 module's docstring).
 
-Everything runs in float64 under a scoped ``jax.experimental.enable_x64``
-(availability targets with many nines need the full mantissa); when jax
-is unavailable the callers fall back to the scalar oracles.  Pad
-planning goes through :mod:`repro.core.shapes` (shared hysteresis-banded
-buckets + compile-cache census).
+Everything runs in float64 on the host CPU device, under
+:func:`repro.core.shapes.decision_scope` held tight around each kernel
+call (availability targets with many nines need the full mantissa, and
+the TPU's emulated float64 does not round as numpy does).  Pad planning goes through :mod:`repro.core.shapes`
+(shared hysteresis-banded buckets + compile-cache census).
 
 **Failure-domain constraints.**  Under ``PlacementConstraints`` both
 greedy schedulers hand these kernels the cap-admitted subsequence of
@@ -70,32 +70,19 @@ from __future__ import annotations
 
 import functools
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from . import shapes
 from .reliability import _AUTO_EXACT_LIMIT, rna_parity_frontier
 
-try:  # pragma: no cover - exercised implicitly by every greedy-kernel test
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import enable_x64
-
-    _JAX_OK = True
-except Exception:  # jax is an optional accelerator dependency
-    _JAX_OK = False
-
 __all__ = [
-    "kernel_available",
     "least_used_batch",
     "min_storage_batch",
     "rna_frontier_row",
 ]
-
-
-def kernel_available() -> bool:
-    """True when the jitted scoring paths can run (jax importable)."""
-    return _JAX_OK
 
 
 def rna_frontier_row(fail_sorted: np.ndarray, target: float, L: int) -> np.ndarray:
@@ -120,135 +107,135 @@ def rna_frontier_row(fail_sorted: np.ndarray, target: float, L: int) -> np.ndarr
     return out
 
 
-if _JAX_OK:
+def _prefix_frontier(probs, target, L, width, n_steps):
+    """Min parity of every prefix of ``probs`` (one masked DP scan).
 
-    def _prefix_frontier(probs, target, L, width, n_steps):
-        """Min parity of every prefix of ``probs`` (one masked DP scan).
+    Jax twin of ``ParityFrontier.upto_many(n_starts=1)`` — and of the
+    exact branch of ``min_parity_for_target`` (full-width DP, cumsum
+    CDF, first feasible index): ``out[i]`` is the min parity of the
+    length-``i+1`` prefix, ``-1`` where infeasible, valid for steps
+    ``i < n_steps``.  ``width`` bounds the tracked parity count (the
+    full ``n_steps + 1`` for exactness).
+    """
 
-        Jax twin of ``ParityFrontier.upto_many(n_starts=1)`` — and of the
-        exact branch of ``min_parity_for_target`` (full-width DP, cumsum
-        CDF, first feasible index): ``out[i]`` is the min parity of the
-        length-``i+1`` prefix, ``-1`` where infeasible, valid for steps
-        ``i < n_steps``.  ``width`` bounds the tracked parity count (the
-        full ``n_steps + 1`` for exactness).
-        """
+    def step(dp, i):
+        p_i = probs[i]
+        shifted = jnp.concatenate([jnp.zeros(1, dp.dtype), dp[:-1]])
+        new_dp = dp * (1.0 - p_i) + shifted * p_i
+        dp = jnp.where(i < L, new_dp, dp)
+        cdf = jnp.cumsum(dp)
+        feas = cdf >= target
+        j = jnp.argmax(feas)
+        ok = jnp.any(feas) & (j <= i) & (i < L)
+        return dp, jnp.where(ok, j, -1).astype(jnp.int64)
 
-        def step(dp, i):
-            p_i = probs[i]
-            shifted = jnp.concatenate([jnp.zeros(1, dp.dtype), dp[:-1]])
-            new_dp = dp * (1.0 - p_i) + shifted * p_i
-            dp = jnp.where(i < L, new_dp, dp)
-            cdf = jnp.cumsum(dp)
-            feas = cdf >= target
-            j = jnp.argmax(feas)
-            ok = jnp.any(feas) & (j <= i) & (i < L)
-            return dp, jnp.where(ok, j, -1).astype(jnp.int64)
+    dp0 = jnp.zeros(width).at[0].set(1.0)
+    _, mp = lax.scan(step, dp0, jnp.arange(n_steps))
+    return mp  # (n_steps,) indexed by prefix length - 1
 
-        dp0 = jnp.zeros(width).at[0].set(1.0)
-        _, mp = lax.scan(step, dp0, jnp.arange(n_steps))
-        return mp  # (n_steps,) indexed by prefix length - 1
 
-    @functools.partial(jax.jit, static_argnums=(0,))
-    def _least_used_scores(
-        L_pad,
-        probs_b,     # (B, L_pad) per-item fail probs in free-desc order
-        size_b,      # (B,)
-        target_b,    # (B,)
-        free,        # (L_pad,) free MB, free-desc order (pad -1)
-        L,           # live-node count (traced; padding masked via L)
-    ):
-        """GreedyLeastUsed (Eq. 5): first N whose exact frontier admits
-        ``K = N - max(1, P*) >= 2`` with the chunk fitting the prefix."""
-        i_idx = jnp.arange(L_pad)
-        n_arr = i_idx + 1
+@functools.partial(jax.jit, static_argnums=(0,))
+def _least_used_scores(
+    L_pad,
+    probs_b,     # (B, L_pad) per-item fail probs in free-desc order
+    size_b,      # (B,)
+    target_b,    # (B,)
+    free,        # (L_pad,) free MB, free-desc order (pad -1)
+    L,           # live-node count (traced; padding masked via L)
+):
+    """GreedyLeastUsed (Eq. 5): first N whose exact frontier admits
+    ``K = N - max(1, P*) >= 2`` with the chunk fitting the prefix."""
+    i_idx = jnp.arange(L_pad)
+    n_arr = i_idx + 1
 
-        def one(probs, size, target):
-            mp = _prefix_frontier(probs, target, L, L_pad + 1, L_pad)
-            p_star = jnp.maximum(1, mp)
-            k = n_arr - p_star
-            k_safe = jnp.maximum(k, 1)
-            chunk = size / k_safe
-            feasible = (
-                (n_arr >= 2)
-                & (n_arr <= L)
-                & (mp >= 0)
-                & (k >= 2)
-                & (free >= chunk)  # free-desc prefix: min free is node N-1
-            )
-            idx = jnp.argmax(feasible)
-            found = jnp.any(feasible)
-            return (
-                found,
-                jnp.where(found, n_arr[idx], 0),
-                jnp.where(found, k[idx], 0),
-                jnp.where(found, p_star[idx], 0),
-            )
+    def one(probs, size, target):
+        mp = _prefix_frontier(probs, target, L, L_pad + 1, L_pad)
+        p_star = jnp.maximum(1, mp)
+        k = n_arr - p_star
+        k_safe = jnp.maximum(k, 1)
+        chunk = size / k_safe
+        feasible = (
+            (n_arr >= 2)
+            & (n_arr <= L)
+            & (mp >= 0)
+            & (k >= 2)
+            & (free >= chunk)  # free-desc prefix: min free is node N-1
+        )
+        idx = jnp.argmax(feasible)
+        found = jnp.any(feasible)
+        return (
+            found,
+            jnp.where(found, n_arr[idx], 0),
+            jnp.where(found, k[idx], 0),
+            jnp.where(found, p_star[idx], 0),
+        )
 
-        return jax.vmap(one)(probs_b, size_b, target_b)
+    return jax.vmap(one)(probs_b, size_b, target_b)
 
-    @functools.partial(jax.jit, static_argnums=(0, 1))
-    def _min_storage_scores(
-        L_pad,
-        EXACT,       # _AUTO_EXACT_LIMIT (static; mapping-size DP/RNA split)
-        probs_b,     # (B, L_pad) per-item fail probs in write-bw-desc order
-        size_b,      # (B,)
-        target_b,    # (B,)
-        rna_b,       # (B, L_pad + 1): host RNA frontier, indexed by N
-        free_bw,     # (L_pad,) free MB, write-bw-desc order (pad -1)
-        L,
-    ):
-        """GreedyMinStorage (Eq. 4): evaluate the per-N fixed point over K
-        in closed form wherever the bw-sorted prefix fits the chunk.
 
-        Returns per-(item, N) rows — ``valid``/``k``/``p``/``cost`` plus a
-        ``slow`` flag for rows whose capacity filter engages (finished on
-        the host; see module docstring).  Rows are indexed by ``N - 1``.
-        """
-        i_idx = jnp.arange(L_pad)
-        n_arr = i_idx + 1
-        fmin = lax.cummin(jnp.where(i_idx < L, free_bw, jnp.inf))
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _min_storage_scores(
+    L_pad,
+    EXACT,       # _AUTO_EXACT_LIMIT (static; mapping-size DP/RNA split)
+    probs_b,     # (B, L_pad) per-item fail probs in write-bw-desc order
+    size_b,      # (B,)
+    target_b,    # (B,)
+    rna_b,       # (B, L_pad + 1): host RNA frontier, indexed by N
+    free_bw,     # (L_pad,) free MB, write-bw-desc order (pad -1)
+    L,
+):
+    """GreedyMinStorage (Eq. 4): evaluate the per-N fixed point over K
+    in closed form wherever the bw-sorted prefix fits the chunk.
 
-        def one(probs, size, target, rna):
-            mp_exact = _prefix_frontier(
-                probs, target, L, min(L_pad, EXACT) + 1, min(L_pad, EXACT)
-            )
-            mp_exact = jnp.concatenate(
-                [mp_exact, jnp.full(L_pad - mp_exact.shape[0], -1, jnp.int64)]
-            )
-            # Frontier per prefix length N: exact DP for N <= EXACT, the
-            # host-computed RNA row above (min_parity_for_target "auto").
-            m_hat = jnp.where(n_arr <= EXACT, mp_exact, rna[1:])
+    Returns per-(item, N) rows — ``valid``/``k``/``p``/``cost`` plus a
+    ``slow`` flag for rows whose capacity filter engages (finished on
+    the host; see module docstring).  Rows are indexed by ``N - 1``.
+    """
+    i_idx = jnp.arange(L_pad)
+    n_arr = i_idx + 1
+    fmin = lax.cummin(jnp.where(i_idx < L, free_bw, jnp.inf))
 
-            in_range = (n_arr >= 2) & (n_arr <= L)
-            chunk0 = size / (n_arr - 1.0)        # first probe: K = N - 1
-            fitcnt0 = jnp.sum(
-                (free_bw[None, :] >= chunk0[:, None]) & (i_idx[None, :] < L),
-                axis=1,
-            )
-            pfit0 = fmin >= chunk0               # whole prefix fits probe 1
-            k1 = n_arr - m_hat                   # second probe: K = N - m_hat
-            pfit1 = fmin >= size / jnp.maximum(k1, 1).astype(jnp.float64)
+    def one(probs, size, target, rna):
+        mp_exact = _prefix_frontier(
+            probs, target, L, min(L_pad, EXACT) + 1, min(L_pad, EXACT)
+        )
+        mp_exact = jnp.concatenate(
+            [mp_exact, jnp.full(L_pad - mp_exact.shape[0], -1, jnp.int64)]
+        )
+        # Frontier per prefix length N: exact DP for N <= EXACT, the
+        # host-computed RNA row above (min_parity_for_target "auto").
+        m_hat = jnp.where(n_arr <= EXACT, mp_exact, rna[1:])
 
-            # Probe 1 accepts immediately when min parity is already <= 1;
-            # otherwise the fixed point re-probes at K = N - m_hat, where an
-            # unchanged (still-prefix) mapping reproduces m_hat and accepts.
-            acc1 = pfit0 & (m_hat >= 0) & (m_hat <= 1)
-            deeper = pfit0 & (m_hat >= 2) & (k1 >= 1)
-            acc2 = deeper & pfit1
-            valid = in_range & (fitcnt0 >= n_arr) & (acc1 | acc2)
-            slow = in_range & (fitcnt0 >= n_arr) & (
-                (~pfit0) | (deeper & ~pfit1)
-            )
-            k = jnp.where(acc1, n_arr - 1, k1)
-            p = jnp.where(acc1, 1, m_hat)
-            cost = jnp.where(
-                valid,
-                (size / k.astype(jnp.float64)) * n_arr.astype(jnp.float64),
-                jnp.inf,
-            )
-            return valid, slow, k, p, cost
+        in_range = (n_arr >= 2) & (n_arr <= L)
+        chunk0 = size / (n_arr - 1.0)        # first probe: K = N - 1
+        fitcnt0 = jnp.sum(
+            (free_bw[None, :] >= chunk0[:, None]) & (i_idx[None, :] < L),
+            axis=1,
+        )
+        pfit0 = fmin >= chunk0               # whole prefix fits probe 1
+        k1 = n_arr - m_hat                   # second probe: K = N - m_hat
+        pfit1 = fmin >= size / jnp.maximum(k1, 1).astype(jnp.float64)
 
-        return jax.vmap(one)(probs_b, size_b, target_b, rna_b)
+        # Probe 1 accepts immediately when min parity is already <= 1;
+        # otherwise the fixed point re-probes at K = N - m_hat, where an
+        # unchanged (still-prefix) mapping reproduces m_hat and accepts.
+        acc1 = pfit0 & (m_hat >= 0) & (m_hat <= 1)
+        deeper = pfit0 & (m_hat >= 2) & (k1 >= 1)
+        acc2 = deeper & pfit1
+        valid = in_range & (fitcnt0 >= n_arr) & (acc1 | acc2)
+        slow = in_range & (fitcnt0 >= n_arr) & (
+            (~pfit0) | (deeper & ~pfit1)
+        )
+        k = jnp.where(acc1, n_arr - 1, k1)
+        p = jnp.where(acc1, 1, m_hat)
+        cost = jnp.where(
+            valid,
+            (size / k.astype(jnp.float64)) * n_arr.astype(jnp.float64),
+            jnp.inf,
+        )
+        return valid, slow, k, p, cost
+
+    return jax.vmap(one)(probs_b, size_b, target_b, rna_b)
 
 
 def _pad_batch(B: int, L: int):
@@ -276,8 +263,6 @@ def least_used_batch(
     length and EC parameters per item (zeros where ``ok`` is False).
     Pure function of its arguments.
     """
-    if not _JAX_OK:  # callers are expected to gate on kernel_available()
-        raise RuntimeError("jax unavailable; use the scalar oracle path")
     B, L = probs_mat.shape
     if L < 2 or B == 0:
         z = np.zeros(B, dtype=np.int64)
@@ -286,7 +271,7 @@ def least_used_batch(
     shapes.record_compile("least_used_kernel", (B_pad, L_pad))
     pm = np.zeros((B_pad, L_pad), dtype=np.float64)
     pm[:B, :L] = probs_mat
-    with enable_x64():
+    with shapes.decision_scope():
         ok, n, k, p = _least_used_scores(
             L_pad,
             jnp.asarray(pm),
@@ -295,6 +280,7 @@ def least_used_batch(
             jnp.asarray(_pad_to(free_s, L_pad, -1.0)),
             np.int64(L),
         )
+    shapes.record_device("least_used_kernel", ok)
     return (
         np.asarray(ok)[:B],
         np.asarray(n, dtype=np.int64)[:B],
@@ -318,8 +304,6 @@ def min_storage_batch(
     scalar fixed point and takes the min-cost row in ascending-N order
     (matching the oracle's strict-less tie-breaking).  Pure function.
     """
-    if not _JAX_OK:
-        raise RuntimeError("jax unavailable; use the scalar oracle path")
     B, L = probs_mat.shape
     if L < 2 or B == 0:
         shape = (B, max(L, 0))
@@ -336,7 +320,7 @@ def min_storage_batch(
     pm[:B, :L] = probs_mat
     rna = np.full((B_pad, L_pad + 1), -1, dtype=np.int64)
     rna[:B, : L + 1] = rna_rows
-    with enable_x64():
+    with shapes.decision_scope():
         valid, slow, k, p, cost = _min_storage_scores(
             L_pad,
             int(_AUTO_EXACT_LIMIT),
@@ -347,6 +331,7 @@ def min_storage_batch(
             jnp.asarray(_pad_to(free_bw, L_pad, -1.0)),
             np.int64(L),
         )
+    shapes.record_device("min_storage_kernel", valid)
     return (
         np.asarray(valid)[:B, :L],
         np.asarray(slow)[:B, :L],

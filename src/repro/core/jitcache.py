@@ -1,113 +1,66 @@
-"""Opt-in persistent (cross-process) XLA compilation cache.
+"""Persistent (cross-process) XLA compilation cache, always on.
 
 The shape-bucket planner (:mod:`repro.core.shapes`) bounds how many
 distinct static signatures a process compiles — geometric rungs plus a
 hysteresis band keep the census small.  This module makes those few
-compiles survive the process: with ``REPRO_JIT_CACHE=1`` in the
-environment, jax's persistent compilation cache is pointed at a
-directory (``REPRO_JIT_CACHE_DIR``, default ``results/.jax_cache/``) so
-a benchmark or CI job's first decision pays a disk read instead of an
-XLA compile when a previous run already compiled the same signature.
-The bucketer is what makes the disk cache effective: stable pads mean
-stable signatures mean cache hits.
+compiles survive the process, so a later run's first decision pays a
+disk read instead of an XLA compile.  That matters most on the TPU,
+where the float64 decision kernels are emulated and one large-cluster
+compile takes minutes.
 
-Strictly opt-in and failure-proof: with the flag unset this module never
-imports jax; with it set, every config knob is applied best-effort (a
-jax build without the persistent-cache knobs just runs uncached).  The
-cold-vs-warm first-decision latency the cache buys is stamped into the
-``table2`` benchmark telemetry (``first_decision`` section) via
-:func:`repro.telemetry.snapshot`.
+Where the cache lives:
+
+* ``JAX_COMPILATION_CACHE_DIR`` set in the environment: jax reads it
+  itself and this module sets no directory of its own;
+* otherwise ``results/.jax_cache`` inside the checkout, resolved from
+  this file (never from the working directory, a temp name, a pid or
+  the time), so every process run from one checkout shares it.
+
+Every compile is cached, however small: the placement kernels are
+individually quick to compile on the CPU but numerous across lanes.
+:func:`status` feeds :func:`repro.telemetry.snapshot`.
 """
 
 from __future__ import annotations
 
 import os
 import pathlib
-from typing import Any, Optional
+from typing import Any
 
-__all__ = [
-    "DEFAULT_DIR",
-    "ENV_DIR",
-    "ENV_FLAG",
-    "cache_dir",
-    "configure",
-    "enabled",
-    "status",
-]
+import jax
 
-#: set non-empty (and not 0/false/no) to activate the persistent cache.
-ENV_FLAG = "REPRO_JIT_CACHE"
-#: overrides the cache directory (default: results/.jax_cache).
-ENV_DIR = "REPRO_JIT_CACHE_DIR"
-DEFAULT_DIR = pathlib.Path("results") / ".jax_cache"
+__all__ = ["ENV_DIR", "CHECKOUT_DIR", "cache_dir", "configure", "status"]
 
-_state: dict[str, Any] = {
-    "configured": False,
-    "active": False,
-    "dir": None,
-    "error": None,
-}
-
-
-def enabled() -> bool:
-    """True when the opt-in env flag is set."""
-    return os.environ.get(ENV_FLAG, "").strip().lower() not in ("", "0", "false", "no")
+#: jax's own environment variable for the cache directory.
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+#: the cache directory when ``ENV_DIR`` is unset (listed in .gitignore).
+CHECKOUT_DIR = pathlib.Path(__file__).resolve().parents[3] / "results" / ".jax_cache"
 
 
 def cache_dir() -> pathlib.Path:
-    return pathlib.Path(os.environ.get(ENV_DIR) or DEFAULT_DIR)
+    """The directory jax reads and writes compiled programs in."""
+    return pathlib.Path(os.environ.get(ENV_DIR) or CHECKOUT_DIR)
 
 
-def configure() -> bool:
-    """Idempotently point jax's persistent compilation cache at
-    :func:`cache_dir` when the env flag is set.
+def configure() -> None:
+    """Point jax's persistent compilation cache at :func:`cache_dir`.
 
     Called on :mod:`repro.core.shapes` import, i.e. before any kernel
     module traces its first jit — the config must precede the first
     compile for that compile to be written to (or served from) disk.
-    Returns True when the cache is active.  Never raises: a missing or
-    knobless jax leaves the process running with in-memory jit only,
-    with the failure recorded in :func:`status`.
+    Idempotent.
     """
-    if _state["configured"]:
-        return _state["active"]
-    _state["configured"] = True
-    if not enabled():
-        return False
-    try:
-        import jax
-
-        d = cache_dir()
-        d.mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(d))
-        # cache every compile however small — the placement kernels are
-        # individually fast to compile but numerous across lanes
-        for knob, val in (
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-            ("jax_persistent_cache_min_entry_size_bytes", -1),
-        ):
-            try:
-                jax.config.update(knob, val)
-            except Exception:
-                pass  # knob absent on this jax version: still cached, just gated
-        _state["active"] = True
-        _state["dir"] = str(d)
-    except Exception as exc:  # jax missing/unimportable: stay opt-out
-        _state["error"] = f"{type(exc).__name__}: {exc}"
-    return _state["active"]
+    if not os.environ.get(ENV_DIR):
+        jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_DIR))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
 def status() -> dict[str, Any]:
-    """Telemetry view: flag state, active dir, entry count, any error."""
-    out = {
-        "enabled": enabled(),
-        "active": bool(_state["active"]),
-        "dir": _state["dir"],
-        "error": _state["error"],
+    """Telemetry view: cache directory, where it came from, entry count."""
+    d = cache_dir()
+    return {
+        "dir": str(d),
+        "source": "env" if os.environ.get(ENV_DIR) else "checkout",
+        "entries": sum(1 for _ in d.iterdir()) if d.is_dir() else 0,
     }
-    if _state["active"] and _state["dir"]:
-        try:
-            out["entries"] = sum(1 for _ in pathlib.Path(_state["dir"]).iterdir())
-        except OSError:
-            out["entries"] = 0
-    return out

@@ -54,11 +54,11 @@ every order-sensitive computation on the host:
 Equivalence across normal, capacity-tight and low-reliability regimes
 is pinned by tests/test_lb_vectorized.py.
 
-Everything runs in float64 under a scoped ``jax.experimental.enable_x64``
-(many-nines availability targets need the full mantissa); when jax is
-unavailable the callers fall back to the scalar oracle.  Pad planning
-goes through :mod:`repro.core.shapes` (shared hysteresis-banded buckets
-+ compile-cache census).
+Everything runs in float64 on the host CPU device, under
+:func:`repro.core.shapes.decision_scope` held tight around the kernel
+call (many-nines availability targets need the full mantissa, and the
+TPU's emulated float64 does not round as numpy does).  Pad planning goes through :mod:`repro.core.shapes` (shared
+hysteresis-banded buckets + compile-cache census).
 
 **Failure-domain constraints.**  Under ``PlacementConstraints`` the
 free-descending node order handed to the grid is the cap-admitted
@@ -76,98 +76,84 @@ from __future__ import annotations
 
 import functools
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from . import shapes
 
-try:  # pragma: no cover - exercised implicitly by every LB-kernel test
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import enable_x64
-
-    _JAX_OK = True
-except Exception:  # jax is an optional accelerator dependency
-    _JAX_OK = False
-
-__all__ = ["kernel_available", "lb_batch"]
+__all__ = ["lb_batch"]
 
 
-def kernel_available() -> bool:
-    """True when the jitted scoring path can run (jax importable)."""
-    return _JAX_OK
+@functools.partial(jax.jit, static_argnums=(0,))
+def _lb_scores(
+    L_pad,
+    mp_b,        # (B, L_pad) host frontier: min parity per prefix length
+    size_b,      # (B,)
+    free,        # (L_pad,) free MB, free-desc order (pad -1)
+    suffix,      # (L_pad + 1,) host suffix penalties by n (pad 0)
+    f_avg,       # scalar: mean free over live nodes (host-computed)
+    L,           # live-node count (traced; padding masked via L)
+):
+    """D-Rex LB (Alg. 1) for a batch: per item, the winning (K, P).
 
+    See the module docstring for the two-phase structure and the
+    exactness policy.  ``mp_b[row, n-1]`` is the min parity of the
+    length-``n`` free-desc prefix (``-1`` infeasible), straight from
+    the oracle's :class:`ParityFrontier`.
+    """
+    k_arr = jnp.arange(L_pad) + 2
+    n_row = jnp.arange(L_pad) + 1
+    i_idx = jnp.arange(L_pad)
+    big = jnp.int64(L_pad + 2)
 
-if _JAX_OK:
+    def one(mp, size):
+        chunk = size / k_arr.astype(jnp.float64)
+        # ---- phase 1: smallest feasible P (line 22), O(L)
+        hi = jnp.where(mp >= 0, n_row - jnp.maximum(1, mp), 0)
+        col_ok = (
+            (n_row <= L)
+            & (hi >= 2)
+            # same float predicate the oracle tests: free[n-1] >= size/K
+            & (free >= size / jnp.maximum(hi, 1).astype(jnp.float64))
+        )
+        p_star = jnp.min(jnp.where(col_ok, jnp.maximum(1, mp), big))
+        ok = p_star < big
+        # ---- phase 2: penalties on the N = K + P* diagonal
+        n_diag = jnp.clip(k_arr + p_star, 2, L_pad)
+        mp_d = mp[n_diag - 1]
+        feas_d = (
+            ok
+            & (k_arr + p_star <= L)
+            & (mp_d >= 0)
+            & (mp_d <= p_star)
+            & (free[n_diag - 1] >= chunk)
+        )
 
-    @functools.partial(jax.jit, static_argnums=(0,))
-    def _lb_scores(
-        L_pad,
-        mp_b,        # (B, L_pad) host frontier: min parity per prefix length
-        size_b,      # (B,)
-        free,        # (L_pad,) free MB, free-desc order (pad -1)
-        suffix,      # (L_pad + 1,) host suffix penalties by n (pad 0)
-        f_avg,       # scalar: mean free over live nodes (host-computed)
-        L,           # live-node count (traced; padding masked via L)
-    ):
-        """D-Rex LB (Alg. 1) for a batch: per item, the winning (K, P).
+        def body(carry, x):
+            run, acc = carry
+            i, f_i = x
+            run = run + jnp.abs(f_i - chunk - f_avg)
+            acc = jnp.where(i == n_diag - 1, run, acc)
+            return (run, acc), None
 
-        See the module docstring for the two-phase structure and the
-        exactness policy.  ``mp_b[row, n-1]`` is the min parity of the
-        length-``n`` free-desc prefix (``-1`` infeasible), straight from
-        the oracle's :class:`ParityFrontier`.
-        """
-        k_arr = jnp.arange(L_pad) + 2
-        n_row = jnp.arange(L_pad) + 1
-        i_idx = jnp.arange(L_pad)
-        big = jnp.int64(L_pad + 2)
+        (_, acc), _ = lax.scan(
+            body,
+            (jnp.zeros(L_pad), jnp.zeros(L_pad)),
+            (i_idx, free),
+        )
+        # lines 10-15: in-mapping prefix sum + precomputed suffix term.
+        bp = jnp.where(feas_d, acc + suffix[n_diag], jnp.inf)
+        bv = jnp.min(bp)
+        k_star = jnp.min(jnp.where(feas_d & (bp == bv), k_arr, big))
+        return (
+            ok,
+            jnp.where(ok, k_star, 0),
+            jnp.where(ok, p_star, 0),
+        )
 
-        def one(mp, size):
-            chunk = size / k_arr.astype(jnp.float64)
-            # ---- phase 1: smallest feasible P (line 22), O(L)
-            hi = jnp.where(mp >= 0, n_row - jnp.maximum(1, mp), 0)
-            col_ok = (
-                (n_row <= L)
-                & (hi >= 2)
-                # same float predicate the oracle tests: free[n-1] >= size/K
-                & (free >= size / jnp.maximum(hi, 1).astype(jnp.float64))
-            )
-            p_star = jnp.min(jnp.where(col_ok, jnp.maximum(1, mp), big))
-            ok = p_star < big
-            # ---- phase 2: penalties on the N = K + P* diagonal
-            n_diag = jnp.clip(k_arr + p_star, 2, L_pad)
-            mp_d = mp[n_diag - 1]
-            feas_d = (
-                ok
-                & (k_arr + p_star <= L)
-                & (mp_d >= 0)
-                & (mp_d <= p_star)
-                & (free[n_diag - 1] >= chunk)
-            )
-
-            def body(carry, x):
-                run, acc = carry
-                i, f_i = x
-                run = run + jnp.abs(f_i - chunk - f_avg)
-                acc = jnp.where(i == n_diag - 1, run, acc)
-                return (run, acc), None
-
-            (_, acc), _ = lax.scan(
-                body,
-                (jnp.zeros(L_pad), jnp.zeros(L_pad)),
-                (i_idx, free),
-            )
-            # lines 10-15: in-mapping prefix sum + precomputed suffix term.
-            bp = jnp.where(feas_d, acc + suffix[n_diag], jnp.inf)
-            bv = jnp.min(bp)
-            k_star = jnp.min(jnp.where(feas_d & (bp == bv), k_arr, big))
-            return (
-                ok,
-                jnp.where(ok, k_star, 0),
-                jnp.where(ok, p_star, 0),
-            )
-
-        return jax.vmap(one)(mp_b, size_b)
+    return jax.vmap(one)(mp_b, size_b)
 
 
 def _pad_to(a: np.ndarray, size: int, fill: float) -> np.ndarray:
@@ -191,8 +177,6 @@ def lb_batch(
     always the free-desc prefix of ``k + p`` nodes).  Pure function of
     its arguments.
     """
-    if not _JAX_OK:  # callers are expected to gate on kernel_available()
-        raise RuntimeError("jax unavailable; use the scalar oracle path")
     B, L = mp_rows.shape
     if L < 3 or B == 0:
         z = np.zeros(B, dtype=np.int64)
@@ -204,7 +188,7 @@ def lb_batch(
     mp[:B, :L] = mp_rows
     suf = np.zeros(L_pad + 1, dtype=np.float64)
     suf[: L + 1] = suffix
-    with enable_x64():
+    with shapes.decision_scope():
         ok, k, p = _lb_scores(
             L_pad,
             jnp.asarray(mp),
@@ -214,6 +198,7 @@ def lb_batch(
             jnp.asarray(np.float64(f_avg)),
             np.int64(L),
         )
+    shapes.record_device("lb_kernel", ok)
     return (
         np.asarray(ok)[:B],
         np.asarray(k, dtype=np.int64)[:B],

@@ -18,11 +18,14 @@ module computes the same decision as one jitted kernel over a padded
   snapshot, which is what lets ``PlacementEngine.place_many`` score a
   queue of items in a single call.
 
-Everything runs in float64 under a scoped ``jax.experimental.enable_x64``
-(the DP discriminates seven-nines availability targets, which float32
-cannot represent), so the kernel is decision-equivalent to the numpy
+Everything runs in float64 on the host CPU device, under
+:func:`repro.core.shapes.decision_scope` held tight around the kernel
+call (the DP discriminates seven-nines availability targets, which
+float32 cannot represent, and the TPU's emulated float64 does not round
+as numpy does), so the kernel is decision-equivalent to the numpy
 oracle; tests/test_sc_vectorized.py enforces this bit-for-bit on pinned
-traces.  When jax is unavailable the callers fall back to the oracle.
+traces.  The scope is thread-local, so coding launches on other threads
+never see 64-bit types.
 
 **Failure-domain constraints.**  Under ``PlacementConstraints`` the
 candidate-node axis arrives already masked: ``DRexSC`` feeds the kernel
@@ -41,181 +44,167 @@ from __future__ import annotations
 import functools
 import math
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from . import shapes
 
-try:  # pragma: no cover - exercised implicitly by every SC test
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import enable_x64
-
-    _JAX_OK = True
-except Exception:  # jax is an optional accelerator dependency
-    _JAX_OK = False
-
-__all__ = ["kernel_available", "score_windows_batch"]
+__all__ = ["score_windows_batch"]
 
 
-def kernel_available() -> bool:
-    """True when the jitted scoring path can run (jax importable)."""
-    return _JAX_OK
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _score_windows(
+    S_pad,
+    L_pad,
+    budget,
+    probs_b,     # (B, L_pad) per-item fail probs in free-desc order
+    size_b,      # (B,)
+    target_b,    # (B,)
+    smin_b,      # (B,) running smallest-item anchor per item
+    fbase_b,     # (B,) sum of per-node saturation over live nodes
+    ssat_b,      # (B,) system saturation scalar
+    free,        # (L_pad,) shared sorted cluster snapshot ------------
+    wb,
+    rb,
+    used,
+    cap,
+    L,           # live-node count (traced; padding is masked via L)
+    inv_l,       # 1 / max(2, L)
+    log_l,       # log(max(2, L))
+    tm,          # (6,) ECTimeModel params e0,e_byte,e_mult,d0,d_byte,d_mult
+):
+    K_c = min(budget, S_pad * L_pad)  # enumerated windows <= budget
+    s_idx = jnp.arange(S_pad)
+    i_idx = jnp.arange(L_pad)
+    act2 = i_idx[None, :] >= s_idx[:, None]  # (S, L): end >= start
 
+    # Bottleneck bandwidth of window [s..i] is a running min over the
+    # suffix starting at s (exact: min has no rounding).
+    wb_min = lax.cummin(jnp.where(act2, wb[None, :], jnp.inf), axis=1)
+    rb_min = lax.cummin(jnp.where(act2, rb[None, :], jnp.inf), axis=1)
 
-if _JAX_OK:
+    # Scalar enumeration order and budget: start s contributes
+    # min(L-1-s, remaining budget) windows, starts in ascending order.
+    w_full = jnp.clip(L - 1 - s_idx, 0, None)
+    cum_before = jnp.concatenate(
+        [jnp.zeros(1, w_full.dtype), jnp.cumsum(w_full)[:-1]]
+    )
+    allowed = jnp.clip(budget - cum_before, 0, w_full)
+    win_idx = i_idx[None, :] - s_idx[:, None] - 1  # 0 <=> window n=2
+    in_budget = (win_idx >= 0) & (win_idx < allowed[:, None])
+    in_budget &= i_idx[None, :] <= L - 1
 
-    @functools.partial(jax.jit, static_argnums=(0, 1, 2))
-    def _score_windows(
-        S_pad,
-        L_pad,
-        budget,
-        probs_b,     # (B, L_pad) per-item fail probs in free-desc order
-        size_b,      # (B,)
-        target_b,    # (B,)
-        smin_b,      # (B,) running smallest-item anchor per item
-        fbase_b,     # (B,) sum of per-node saturation over live nodes
-        ssat_b,      # (B,) system saturation scalar
-        free,        # (L_pad,) shared sorted cluster snapshot ------------
-        wb,
-        rb,
-        used,
-        cap,
-        L,           # live-node count (traced; padding is masked via L)
-        inv_l,       # 1 / max(2, L)
-        log_l,       # log(max(2, L))
-        tm,          # (6,) ECTimeModel params e0,e_byte,e_mult,d0,d_byte,d_mult
-    ):
-        K_c = min(budget, S_pad * L_pad)  # enumerated windows <= budget
-        s_idx = jnp.arange(S_pad)
-        i_idx = jnp.arange(L_pad)
-        act2 = i_idx[None, :] >= s_idx[:, None]  # (S, L): end >= start
+    # Compact the (S, L) window grid to a fixed candidate axis in the
+    # scalar path's (start-major, length-minor) order: a stable sort
+    # moves the <= budget enumerated windows to the front unpermuted.
+    flat_order = jnp.argsort(
+        jnp.where(in_budget.ravel(), 0, 1).astype(jnp.int32)
+    )[:K_c]
+    s_w = flat_order // L_pad
+    i_w = flat_order % L_pad
+    enumerated = in_budget.ravel()[flat_order]
+    n_w = i_w - s_w + 1
 
-        # Bottleneck bandwidth of window [s..i] is a running min over the
-        # suffix starting at s (exact: min has no rounding).
-        wb_min = lax.cummin(jnp.where(act2, wb[None, :], jnp.inf), axis=1)
-        rb_min = lax.cummin(jnp.where(act2, rb[None, :], jnp.inf), axis=1)
+    e0, e_byte, e_mult, d0, d_byte, d_mult = (
+        tm[0], tm[1], tm[2], tm[3], tm[4], tm[5]
+    )
 
-        # Scalar enumeration order and budget: start s contributes
-        # min(L-1-s, remaining budget) windows, starts in ascending order.
-        w_full = jnp.clip(L - 1 - s_idx, 0, None)
-        cum_before = jnp.concatenate(
-            [jnp.zeros(1, w_full.dtype), jnp.cumsum(w_full)[:-1]]
+    def saturation(x, c, smin):
+        # Mirror of algorithms.saturation_score (elementwise, f64).
+        span = jnp.maximum(c - smin, 1e-9)
+        u = jnp.clip((x - smin) / span, 0.0, 1.0)
+        return jnp.clip(inv_l * jnp.exp(log_l * u), 0.0, 1.0)
+
+    def one(probs, size, target, smin, f_base_sum, sys_sat):
+        # ---- parity frontier of every suffix, one masked DP --------
+        def step(dp, i):
+            p_i = probs[i]
+            shifted = jnp.concatenate(
+                [jnp.zeros((S_pad, 1), dp.dtype), dp[:, :-1]], axis=1
+            )
+            new_dp = dp * (1.0 - p_i) + shifted * p_i
+            dp = jnp.where((i >= s_idx)[:, None], new_dp, dp)
+            cdf = jnp.cumsum(dp, axis=1)
+            feas = cdf >= target
+            j = jnp.argmax(feas, axis=1)
+            n_len = i - s_idx + 1
+            ok = jnp.any(feas, axis=1) & (j <= n_len - 1)
+            return dp, jnp.where(ok, j, -1)
+
+        dp0 = jnp.zeros((S_pad, L_pad + 1)).at[:, 0].set(1.0)
+        _, cols = lax.scan(step, dp0, i_idx)
+        mp = cols.T[s_w, i_w]  # (K_c,) min parity per window
+
+        p_star = jnp.maximum(1, mp)
+        k = n_w - p_star
+        valid = enumerated & (mp >= 0) & (k >= 1)
+        k_safe = jnp.where(valid, k, 1)
+        chunk = size / k_safe
+        # Mapping is free-desc sorted: the window min free is its
+        # last node (index i).
+        valid &= free[i_w] >= chunk
+
+        enc = jnp.where(
+            k_safe == 1,
+            e0,
+            e0 + e_byte * size + e_mult * (n_w - k_safe) * size,
         )
-        allowed = jnp.clip(budget - cum_before, 0, w_full)
-        win_idx = i_idx[None, :] - s_idx[:, None] - 1  # 0 <=> window n=2
-        in_budget = (win_idx >= 0) & (win_idx < allowed[:, None])
-        in_budget &= i_idx[None, :] <= L - 1
+        dec = jnp.where(
+            k_safe == 1, d0, d0 + d_byte * size + d_mult * k_safe * size
+        )
+        duration = (
+            chunk / wb_min[s_w, i_w] + chunk / rb_min[s_w, i_w] + enc + dec
+        )
+        storage = chunk * n_w
 
-        # Compact the (S, L) window grid to a fixed candidate axis in the
-        # scalar path's (start-major, length-minor) order: a stable sort
-        # moves the <= budget enumerated windows to the front unpermuted.
-        flat_order = jnp.argsort(
-            jnp.where(in_budget.ravel(), 0, 1).astype(jnp.int32)
-        )[:K_c]
-        s_w = flat_order // L_pad
-        i_w = flat_order % L_pad
-        enumerated = in_budget.ravel()[flat_order]
-        n_w = i_w - s_w + 1
+        # Saturation objective: base sum over all live nodes plus the
+        # delta of the window's nodes at projected occupancy.
+        in_win = (i_idx[None, :] >= s_w[:, None]) & (
+            i_idx[None, :] <= i_w[:, None]
+        )
+        delta = (
+            (
+                saturation(used[None, :] + chunk[:, None], cap[None, :], smin)
+                - saturation(used, cap, smin)[None, :]
+            )
+            * in_win
+        ).sum(axis=1)
+        sat_obj = f_base_sum + delta
 
-        e0, e_byte, e_mult, d0, d_byte, d_mult = (
-            tm[0], tm[1], tm[2], tm[3], tm[4], tm[5]
+        # ---- Pareto front + relative-progress scoring (lines 11-17)
+        dur_f = jnp.where(valid, duration, jnp.inf)
+        sto_f = jnp.where(valid, storage, jnp.inf)
+        sat_f = jnp.where(valid, sat_obj, jnp.inf)
+        le = jnp.ones((K_c, K_c), bool)
+        lt = jnp.zeros((K_c, K_c), bool)
+        for c in (dur_f, sto_f, sat_f):
+            le &= c[None, :] <= c[:, None]
+            lt |= c[None, :] < c[:, None]
+        front = ~jnp.any(le & lt, axis=1) & valid
+
+        def progress(v):
+            lo = jnp.min(jnp.where(front, v, jnp.inf))
+            hi = jnp.max(jnp.where(front, v, -jnp.inf))
+            return jnp.where(hi - lo <= 1e-12, 0.0, (hi - v) / (hi - lo))
+
+        score = (1.0 - sys_sat) * progress(dur_f) + (
+            progress(sto_f) + progress(sat_f)
+        ) / 2.0
+        best = jnp.argmax(jnp.where(front, score, -jnp.inf))
+        bp = jnp.maximum(1, mp[best])
+        return (
+            jnp.any(valid),
+            s_w[best],
+            n_w[best],
+            n_w[best] - bp,
+            bp,
         )
 
-        def saturation(x, c, smin):
-            # Mirror of algorithms.saturation_score (elementwise, f64).
-            span = jnp.maximum(c - smin, 1e-9)
-            u = jnp.clip((x - smin) / span, 0.0, 1.0)
-            return jnp.clip(inv_l * jnp.exp(log_l * u), 0.0, 1.0)
-
-        def one(probs, size, target, smin, f_base_sum, sys_sat):
-            # ---- parity frontier of every suffix, one masked DP --------
-            def step(dp, i):
-                p_i = probs[i]
-                shifted = jnp.concatenate(
-                    [jnp.zeros((S_pad, 1), dp.dtype), dp[:, :-1]], axis=1
-                )
-                new_dp = dp * (1.0 - p_i) + shifted * p_i
-                dp = jnp.where((i >= s_idx)[:, None], new_dp, dp)
-                cdf = jnp.cumsum(dp, axis=1)
-                feas = cdf >= target
-                j = jnp.argmax(feas, axis=1)
-                n_len = i - s_idx + 1
-                ok = jnp.any(feas, axis=1) & (j <= n_len - 1)
-                return dp, jnp.where(ok, j, -1)
-
-            dp0 = jnp.zeros((S_pad, L_pad + 1)).at[:, 0].set(1.0)
-            _, cols = lax.scan(step, dp0, i_idx)
-            mp = cols.T[s_w, i_w]  # (K_c,) min parity per window
-
-            p_star = jnp.maximum(1, mp)
-            k = n_w - p_star
-            valid = enumerated & (mp >= 0) & (k >= 1)
-            k_safe = jnp.where(valid, k, 1)
-            chunk = size / k_safe
-            # Mapping is free-desc sorted: the window min free is its
-            # last node (index i).
-            valid &= free[i_w] >= chunk
-
-            enc = jnp.where(
-                k_safe == 1,
-                e0,
-                e0 + e_byte * size + e_mult * (n_w - k_safe) * size,
-            )
-            dec = jnp.where(
-                k_safe == 1, d0, d0 + d_byte * size + d_mult * k_safe * size
-            )
-            duration = (
-                chunk / wb_min[s_w, i_w] + chunk / rb_min[s_w, i_w] + enc + dec
-            )
-            storage = chunk * n_w
-
-            # Saturation objective: base sum over all live nodes plus the
-            # delta of the window's nodes at projected occupancy.
-            in_win = (i_idx[None, :] >= s_w[:, None]) & (
-                i_idx[None, :] <= i_w[:, None]
-            )
-            delta = (
-                (
-                    saturation(used[None, :] + chunk[:, None], cap[None, :], smin)
-                    - saturation(used, cap, smin)[None, :]
-                )
-                * in_win
-            ).sum(axis=1)
-            sat_obj = f_base_sum + delta
-
-            # ---- Pareto front + relative-progress scoring (lines 11-17)
-            dur_f = jnp.where(valid, duration, jnp.inf)
-            sto_f = jnp.where(valid, storage, jnp.inf)
-            sat_f = jnp.where(valid, sat_obj, jnp.inf)
-            le = jnp.ones((K_c, K_c), bool)
-            lt = jnp.zeros((K_c, K_c), bool)
-            for c in (dur_f, sto_f, sat_f):
-                le &= c[None, :] <= c[:, None]
-                lt |= c[None, :] < c[:, None]
-            front = ~jnp.any(le & lt, axis=1) & valid
-
-            def progress(v):
-                lo = jnp.min(jnp.where(front, v, jnp.inf))
-                hi = jnp.max(jnp.where(front, v, -jnp.inf))
-                return jnp.where(hi - lo <= 1e-12, 0.0, (hi - v) / (hi - lo))
-
-            score = (1.0 - sys_sat) * progress(dur_f) + (
-                progress(sto_f) + progress(sat_f)
-            ) / 2.0
-            best = jnp.argmax(jnp.where(front, score, -jnp.inf))
-            bp = jnp.maximum(1, mp[best])
-            return (
-                jnp.any(valid),
-                s_w[best],
-                n_w[best],
-                n_w[best] - bp,
-                bp,
-            )
-
-        return jax.vmap(one)(
-            probs_b, size_b, target_b, smin_b, fbase_b, ssat_b
-        )
+    return jax.vmap(one)(
+        probs_b, size_b, target_b, smin_b, fbase_b, ssat_b
+    )
 
 
 def _shape_plan(L: int, budget: int) -> tuple[int, int]:
@@ -260,8 +249,6 @@ def score_windows_batch(
     *cluster*, not of the slice handed to the kernel, so it must come
     from the caller.  Defaults to the array length (unfiltered call).
     """
-    if not _JAX_OK:  # callers are expected to gate on kernel_available()
-        raise RuntimeError("jax unavailable; use the scalar oracle path")
     B, L = probs_mat.shape
     if L < 2 or B == 0:
         z = np.zeros(B, dtype=np.int64)
@@ -284,7 +271,7 @@ def score_windows_batch(
         return out
 
     l_eff = max(2, L if n_live is None else int(n_live))
-    with enable_x64():
+    with shapes.decision_scope():
         ok, s, n, k, p = _score_windows(
             S_pad,
             L_pad,
@@ -305,6 +292,7 @@ def score_windows_batch(
             np.float64(math.log(l_eff)),
             jnp.asarray(np.asarray(tm_params, dtype=np.float64)),
         )
+    shapes.record_device("sc_kernel", ok)
     return (
         np.asarray(ok)[:B],
         np.asarray(s, dtype=np.int64)[:B],

@@ -29,6 +29,10 @@ the one place pad planning lives:
   (``benchmarks/table2_overhead.py`` stamps it into the ``batched_lb``
   section) and pinned by the churn-budget regression test in
   tests/test_shapes.py.
+* **Decision device.**  :func:`decision_scope` is the one place that
+  says where the float64 decision kernels run (:data:`DECISION_PLATFORM`),
+  and :func:`record_device` / :func:`kernel_devices` report where each
+  kernel's outputs actually landed.
 
 Pads only ever *enlarge* the masked region of a kernel's tensors; the
 kernels mask every padded lane via the traced live-node count, so
@@ -38,25 +42,31 @@ golden-equivalence suites run under arbitrary bucket histories).
 
 from __future__ import annotations
 
+import contextlib
 import threading
+
+import jax
 
 from . import jitcache
 
 # Shapes is imported by every kernel module before its first jit trace,
 # so this is the one spot early enough to point jax's persistent
-# compilation cache at disk (opt-in via REPRO_JIT_CACHE=1; no-op — and
-# no jax import — otherwise).  Stable bucketed pads => stable static
+# compilation cache at disk.  Stable bucketed pads => stable static
 # signatures => the disk cache actually hits across processes.
 jitcache.configure()
 
 __all__ = [
     "ShapeBucketer",
     "batch_pad",
+    "DECISION_PLATFORM",
     "compile_cache_stats",
+    "decision_scope",
     "ec_block_pad",
     "issued_shapes",
+    "kernel_devices",
     "node_pad",
     "record_compile",
+    "record_device",
     "reset",
     "start_pad",
 ]
@@ -78,6 +88,25 @@ GROWTH = 1.25
 #: rung for the requested size — i.e. a cluster must shrink below about
 #: half the held pad before the bucketer lets the shape shrink.
 SHRINK_BAND = 2.0
+
+#: Platform of the device every float64 decision kernel (SC, LB and both
+#: greedys) runs on, whatever the default backend is: the host CPU.  The
+#: TPU emulates float64, and its emulated add, multiply, divide and exp
+#: do not round as IEEE-754 (and so numpy) does; decisions computed there
+#: differ from the scalar oracle (ROADMAP Reach item 2.1).  The coding
+#: kernels are unaffected and run on the default backend.
+DECISION_PLATFORM = "cpu"
+
+
+@contextlib.contextmanager
+def decision_scope():
+    """float64 on the decision device, held tight around one kernel call.
+
+    Both settings are thread-local, so coding launches on other threads
+    (the checkpoint pipeline) keep 32-bit types and the default device.
+    """
+    with jax.enable_x64(True), jax.default_device(jax.devices(DECISION_PLATFORM)[0]):
+        yield
 
 
 def _round_up(x: int, m: int) -> int:
@@ -117,6 +146,8 @@ class ShapeBucketer:
         self._compiled: dict[str, set[tuple]] = {}
         #: kernel name -> total batch calls recorded.
         self._calls: dict[str, int] = {}
+        #: kernel name -> devices its outputs landed on.
+        self._devices: dict[str, set[str]] = {}
         self.queries = 0
         self.band_hits = 0
 
@@ -152,6 +183,16 @@ class ShapeBucketer:
         with self._lock:
             return frozenset(self._compiled.get(kernel, ()))
 
+    def record_device(self, kernel: str, out) -> None:
+        """Note the device(s) holding one kernel call's output array."""
+        names = {f"{d.platform}:{d.id}" for d in out.devices()}
+        with self._lock:
+            self._devices.setdefault(kernel, set()).update(names)
+
+    def kernel_devices(self) -> dict[str, tuple[str, ...]]:
+        with self._lock:
+            return {k: tuple(sorted(v)) for k, v in sorted(self._devices.items())}
+
     def stats(self) -> dict:
         """Telemetry snapshot: per-kernel compile/call counts plus the
         bucketer's own query/band counters."""
@@ -172,6 +213,7 @@ class ShapeBucketer:
             self._held.clear()
             self._compiled.clear()
             self._calls.clear()
+            self._devices.clear()
             self.queries = 0
             self.band_hits = 0
 
@@ -223,6 +265,14 @@ def record_compile(kernel: str, signature: tuple) -> bool:
 
 def issued_shapes(kernel: str) -> frozenset:
     return DEFAULT.issued_shapes(kernel)
+
+
+def record_device(kernel: str, out) -> None:
+    DEFAULT.record_device(kernel, out)
+
+
+def kernel_devices() -> dict[str, tuple[str, ...]]:
+    return DEFAULT.kernel_devices()
 
 
 def compile_cache_stats() -> dict:

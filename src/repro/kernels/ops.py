@@ -28,12 +28,13 @@ bytes in, bytes out, bit-identical to the per-item oracle):
   churn XLA compiles; every launch records its static signature through
   the shared compile census (``compile_cache_stats``).
 
-Backend dispatch: the Pallas bit-matmul targets the TPU MXU; off-TPU the
-kernel path runs the jitted XLA bit-matmul (``ref.bitmatmul_ref`` under
-``jax.jit``) — the same unpack/matmul/pack algorithm, so CPU CI both
-tests and *times* the kernel path instead of interpreting Pallas.
-``pallas=True`` forces the Pallas kernel (interpret mode off-TPU; the
-correctness harness in tests/test_kernels.py).
+Backend dispatch: on the TPU the kernel path always runs the Pallas
+bit-matmul on the MXU.  On the CPU backend it runs the jitted, tiled XLA
+twin (``ref.bitmatmul_ref`` under ``jax.jit``) — the same
+unpack/matmul/pack algorithm.  ``interpret=True`` runs the Pallas kernel
+in the Pallas interpreter instead (the CPU correctness harness in
+tests/test_kernels.py asks for it); interpret mode is never chosen
+automatically.
 """
 
 from __future__ import annotations
@@ -184,20 +185,27 @@ def _bitmatmul(
     data: jax.Array,
     *,
     block_bytes: int,
-    pallas: bool | None,
+    interpret: bool,
 ) -> jax.Array:
-    """One coding launch on a block-aligned (K, B) byte matrix."""
-    if pallas is None:
-        pallas = jax.default_backend() == "tpu"
+    """One coding launch on a block-aligned (K, B) byte matrix: Pallas on
+    the TPU (or interpreted, when asked), the XLA twin on the CPU."""
+    if interpret:
+        path = "pallas-interpret"
+    elif jax.default_backend() == "tpu":
+        path = "pallas"
+    else:
+        path = "xla"
     r8, k8 = bitm.shape
     _shapes.record_compile(
         CENSUS_KERNEL,
-        (r8, k8, data.shape[1] // block_bytes, block_bytes,
-         "pallas" if pallas else "xla"),
+        (r8, k8, data.shape[1] // block_bytes, block_bytes, path),
     )
-    if pallas:
-        return gf_bitmatmul(bitm, data, block_bytes=block_bytes)
-    return _bitmatmul_xla(bitm, data, block_bytes=block_bytes)
+    if path == "xla":
+        out = _bitmatmul_xla(bitm, data, block_bytes=block_bytes)
+    else:
+        out = gf_bitmatmul(bitm, data, block_bytes=block_bytes, interpret=interpret)
+    _shapes.record_device(CENSUS_KERNEL, out)
+    return out
 
 
 # -- per-item API (the bit-for-bit oracle for the _many paths) ---------------
@@ -208,7 +216,7 @@ def encode_chunks(
     *,
     block_bytes: int = DEFAULT_BLOCK_BYTES,
     use_kernel: bool = True,
-    pallas: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Parity chunks (P, B) for systematic Cauchy-RS over (K, B) data."""
     data = jnp.asarray(data_chunks, dtype=jnp.uint8)
@@ -219,7 +227,7 @@ def encode_chunks(
     if not use_kernel:
         return _ref.encode_ref(data, jnp.asarray(cauchy))
     padded, b = _pad_to_bucket(data, block_bytes)
-    out = _bitmatmul(bitm, padded, block_bytes=block_bytes, pallas=pallas)
+    out = _bitmatmul(bitm, padded, block_bytes=block_bytes, interpret=interpret)
     return out[:, :b]
 
 
@@ -231,7 +239,7 @@ def decode_chunks(
     *,
     block_bytes: int = DEFAULT_BLOCK_BYTES,
     use_kernel: bool = True,
-    pallas: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Reconstruct the K data chunks from any K surviving chunk rows.
 
@@ -244,7 +252,7 @@ def decode_chunks(
     if not use_kernel:
         return _ref.decode_ref(surv, jnp.asarray(dec))
     padded, b = _pad_to_bucket(surv, block_bytes)
-    out = _bitmatmul(bitm, padded, block_bytes=block_bytes, pallas=pallas)
+    out = _bitmatmul(bitm, padded, block_bytes=block_bytes, interpret=interpret)
     return out[:, :b]
 
 
@@ -258,7 +266,7 @@ def _matmul_concat(
     *,
     block_bytes: int,
     use_kernel: bool,
-    pallas: bool | None,
+    interpret: bool,
 ) -> list[np.ndarray]:
     """Apply one coding matrix to many (K, B_i) matrices in one launch."""
     widths = [m.shape[1] for m in mats]
@@ -275,7 +283,7 @@ def _matmul_concat(
         if use_kernel:
             padded, _ = _pad_to_bucket(cat, block_bytes)
             out = _bitmatmul(
-                bitm, padded, block_bytes=block_bytes, pallas=pallas
+                bitm, padded, block_bytes=block_bytes, interpret=interpret
             )[:, :total]
         else:
             out = _ref.gf_matmul_ref(jnp.asarray(gf_matrix), cat)
@@ -293,7 +301,7 @@ def encode_chunks_many(
     *,
     block_bytes: int = DEFAULT_BLOCK_BYTES,
     use_kernel: bool = True,
-    pallas: bool | None = None,
+    interpret: bool = False,
 ) -> list[np.ndarray]:
     """Parity for a cohort of (K, B_i) data matrices sharing K and P.
 
@@ -314,7 +322,7 @@ def encode_chunks_many(
     cauchy, bitm = _encode_matrices(k, p)
     return _matmul_concat(
         mats, cauchy, bitm, p,
-        block_bytes=block_bytes, use_kernel=use_kernel, pallas=pallas,
+        block_bytes=block_bytes, use_kernel=use_kernel, interpret=interpret,
     )
 
 
@@ -326,7 +334,7 @@ def decode_chunks_many(
     *,
     block_bytes: int = DEFAULT_BLOCK_BYTES,
     use_kernel: bool = True,
-    pallas: bool | None = None,
+    interpret: bool = False,
 ) -> list[np.ndarray]:
     """Reconstruct many items sharing (K, P): one launch per distinct
     erasure pattern (the decode matrix depends on the surviving rows).
@@ -343,7 +351,7 @@ def decode_chunks_many(
         dec, bitm = _decode_matrices(k, p, rows_key)
         got = _matmul_concat(
             [mats[i] for i in idxs], dec, bitm, k,
-            block_bytes=block_bytes, use_kernel=use_kernel, pallas=pallas,
+            block_bytes=block_bytes, use_kernel=use_kernel, interpret=interpret,
         )
         for i, out in zip(idxs, got):
             outs[i] = out

@@ -1,6 +1,6 @@
 """Pallas TPU kernel: Cauchy-RS coding as a mod-2 bit-matrix MXU matmul.
 
-TPU adaptation of the paper's GF(2^8) hot-spot (DESIGN.md §4): instead of
+TPU adaptation of the paper's GF(2^8) hot-spot: instead of
 the CPU's 256-entry lookup-table gathers (hostile to the MXU), the code's
 GF(2)-linearity turns encode/decode into one dense {0,1} matmul
 
@@ -19,9 +19,7 @@ Grid: 1-D over byte columns. Per-program VMEM working set for block size
 The byte dimension block (lane dimension) is kept a multiple of 128; the
 bit dimensions (8K, 8R) are multiples of 8 and are padded by Mosaic to
 the MXU's 128 where needed — for the small K of storage codes the MXU is
-underutilized in one dimension, which is intrinsic to the problem shape
-(see EXPERIMENTS.md §Roofline for the kernel's arithmetic-intensity
-analysis).
+underutilized in one dimension, which is intrinsic to the problem shape.
 """
 
 from __future__ import annotations
@@ -58,16 +56,17 @@ def gf_bitmatmul(
     data_chunks: jax.Array,
     *,
     block_bytes: int = DEFAULT_BLOCK_BYTES,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """out (R, B) u8 = GF(2^8) matrix-product via bit-matmul.
 
     ``bit_matrix``: (8R, 8K) f32 in {0,1} (from gf_to_bitmatrix).
     ``data_chunks``: (K, B) uint8, B a multiple of ``block_bytes``
-    (ops.py pads).
+    (ops.py pads).  ``interpret=True`` runs the Pallas interpreter (the
+    CPU correctness tests ask for it); it is never chosen automatically.
+    Call outside any ``jax.enable_x64`` scope: Mosaic rejects the 64-bit
+    grid indices such a scope produces.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     r8, k8 = bit_matrix.shape
     assert r8 % 8 == 0 and k8 % 8 == 0, bit_matrix.shape
     r, k = r8 // 8, k8 // 8

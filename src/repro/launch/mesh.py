@@ -4,26 +4,17 @@
 importing this module never touches jax device state; the dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
 import and only then calls it.
-
-``AxisType`` (explicit-sharding axis annotations) only exists on newer
-jax; on jax <= 0.4.x meshes carry no axis types and ``jax.make_mesh``
-does not accept the kwarg, so we fall back to plain meshes.
 """
 
 from __future__ import annotations
 
 import jax
-import jax.sharding
-from jax.sharding import Mesh
-
-#: None on jax versions without explicit-sharding axis types (<= 0.4.x).
-AxisType = getattr(jax.sharding, "AxisType", None)
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh_compat(shape, axes) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -30,16 +30,6 @@ from jax.sharding import PartitionSpec as P
 
 from .config import ModelConfig
 
-# jax >= 0.5 exposes shard_map at top level with `check_vma`; jax <= 0.4.x
-# has the experimental module with `check_rep` — same semantics here.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _CHECK_KWARGS = {"check_vma": False}
-else:  # pragma: no cover - exercised on jax <= 0.4.x images
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KWARGS = {"check_rep": False}
-
 
 def _local_moe(xt, router, wg, wi, wo, *, cfg: ModelConfig, n_model: int,
                fsdp_axes):
@@ -120,7 +110,7 @@ def moe_apply_shardmap(p, x, cfg: ModelConfig, mesh):
 
     x2 = x.reshape(b * t, d)
     fn = partial(_local_moe, cfg=cfg, n_model=n_model, fsdp_axes=fsdp_axes)
-    out = _shard_map(
+    out = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(
@@ -131,6 +121,6 @@ def moe_apply_shardmap(p, x, cfg: ModelConfig, mesh):
             P("model", None, fsdp),              # wo
         ),
         out_specs=P(dp_axes or None, None),
-        **_CHECK_KWARGS,
+        check_vma=False,
     )(x2, p["router"], p["wg"], p["wi"], p["wo"])
     return out.reshape(b, t, d)

@@ -6,9 +6,9 @@ the EC coefficient-matrix caches
 (:func:`repro.kernels.ops.matrix_cache_stats`), the shape-bucketer
 compile census (:func:`repro.core.shapes.compile_cache_stats`), the
 per-engine :class:`~repro.core.engine.PlacementEngine` decision counters
-(``engine.stats``), and the opt-in persistent XLA compilation cache
-(:func:`repro.core.jitcache.status`).  Importing each module ad hoc couples every
-benchmark to four internal layouts; this facade freezes one stable
+(``engine.stats``), and the persistent XLA compilation cache
+(:func:`repro.core.jitcache.status`).  Importing each module ad hoc
+couples every benchmark to four internal layouts; this facade freezes one stable
 schema (:class:`TelemetrySnapshot`) behind :func:`snapshot` /
 :func:`reset`.
 
@@ -50,7 +50,7 @@ class TelemetrySnapshot:
     #: ``None`` when no engine was given.
     engine: Optional[dict[str, Any]] = None
     #: persistent XLA compilation-cache state —
-    #: ``repro.core.jitcache.status()`` (opt-in via REPRO_JIT_CACHE=1).
+    #: ``repro.core.jitcache.status()``.
     jit_cache: Optional[dict[str, Any]] = None
 
     def as_dict(self) -> dict[str, Any]:

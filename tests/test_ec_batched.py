@@ -61,12 +61,12 @@ class TestEncodeMany:
         assert ops.encode_chunks_many([], 2) == []
 
     def test_pallas_interpret_matches(self):
-        """The forced-Pallas cohort launch (interpret off-TPU) agrees."""
+        """The Pallas cohort launch, interpreted on the CPU, agrees."""
         datas = [
             np.random.default_rng(i).integers(0, 256, size=(4, 3000), dtype=np.uint8)
             for i in range(3)
         ]
-        got = ops.encode_chunks_many(datas, 2, pallas=True)
+        got = ops.encode_chunks_many(datas, 2, interpret=True)
         want = [np.asarray(ops.encode_chunks(d, 2, use_kernel=False)) for d in datas]
         for g, w in zip(got, want):
             np.testing.assert_array_equal(g, w)
@@ -236,3 +236,32 @@ class TestEmptyPayload:
         keep = np.array([0, 1, 3])
         parts = [(c[keep], keep, len(pl)) for c, pl in zip(chunks, payloads)]
         assert codec.decode_many(parts) == payloads
+
+
+class TestX64ScopeIsThreadLocal:
+    """The decision kernels hold ``jax.enable_x64`` on the placing
+    thread while the checkpoint pipeline codes on others; Mosaic rejects
+    the 64-bit grid indices an x64 scope would give a coding launch, so
+    the scope must not leak across threads."""
+
+    def test_encode_on_other_thread_sees_no_x64(self):
+        import threading
+
+        import jax
+
+        data = np.random.default_rng(3).integers(0, 256, (4, 3000), np.uint8)
+        got: dict = {}
+
+        def worker():
+            got["x64"] = jax.config.jax_enable_x64
+            got["parity"] = np.asarray(ops.encode_chunks(data, 2, interpret=True))
+
+        with jax.enable_x64(True):
+            assert jax.config.jax_enable_x64
+            t = threading.Thread(target=worker)
+            t.start()
+            t.join(timeout=120)
+        assert not t.is_alive()
+        assert got["x64"] is False
+        want = np.asarray(ops.encode_chunks(data, 2, use_kernel=False))
+        np.testing.assert_array_equal(got["parity"], want)

@@ -37,9 +37,6 @@ from repro.core.reliability import (
 )
 from repro.storage import make_node_set, make_trace
 
-needs_jax = pytest.mark.skipif(
-    not greedy_kernel.kernel_available(), reason="jax unavailable"
-)
 
 GREEDY = ("greedy_min_storage", "greedy_least_used")
 
@@ -152,14 +149,12 @@ class TestGoldenPlacements:
         want = [Placement(k, p, ids) for k, p, ids in GOLDEN[name][key]]
         assert got == want
 
-    @needs_jax
     @pytest.mark.parametrize("name,key", GOLDEN_KEYS)
     def test_kernel_matches_golden(self, name, key):
         got = self._run(*key, forced_kernel_scheduler(name))
         want = [Placement(k, p, ids) for k, p, ids in GOLDEN[name][key]]
         assert got == want
 
-    @needs_jax
     @pytest.mark.parametrize("name,key", GOLDEN_KEYS)
     def test_batched_place_many_matches_golden(self, name, key):
         nodeset, seed = key
@@ -172,7 +167,6 @@ class TestGoldenPlacements:
         assert got == want
 
 
-@needs_jax
 @pytest.mark.parametrize("name", GREEDY)
 class TestKernelOracleEquivalence:
     """Kernel decisions == scalar oracle decisions, bit for bit."""
@@ -292,7 +286,6 @@ class TestKernelOracleEquivalence:
         assert sched.smin_mb == smin0
 
 
-@needs_jax
 class TestHybridFallbacks:
     """The kernels' host-side completion paths, exercised explicitly."""
 
@@ -358,7 +351,6 @@ class TestHybridFallbacks:
             assert row[i] == (-1 if want is None else want)
 
 
-@needs_jax
 class TestBatchContextRnaCache:
     def test_rna_rows_are_cached_and_exact(self):
         ctx = BatchContext()

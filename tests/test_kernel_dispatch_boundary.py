@@ -20,10 +20,6 @@ import pytest
 from repro.core import ClusterView, DataItem, StorageNode, create_scheduler
 from repro.core import greedy_kernel, lb_kernel, sc_kernel
 
-needs_jax = pytest.mark.skipif(
-    not (sc_kernel.kernel_available() and greedy_kernel.kernel_available()),
-    reason="jax unavailable",
-)
 
 #: (scheduler, boundary override or None for the class default,
 #:  kernel module, batch entry point the spy wraps)
@@ -66,7 +62,6 @@ def boundary_items(count: int = 4):
     ]
 
 
-@needs_jax
 @pytest.mark.parametrize("delta", [-1, 0, 1])
 @pytest.mark.parametrize("name,override,module,entry", CASES)
 class TestDispatchBoundary:

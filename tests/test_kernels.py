@@ -84,9 +84,9 @@ class TestBitmatrix:
 
 
 class TestPallasKernel:
-    """``pallas=True`` forces the Pallas kernel (interpret mode on CPU)
-    — the correctness gate for the kernel body itself.  Without it the
-    kernel path dispatches to the jitted XLA twin off-TPU (see
+    """``interpret=True`` runs the Pallas kernel in the interpreter on
+    the CPU — the correctness gate for the kernel body itself.  Without
+    it the CPU kernel path is the jitted XLA twin (see
     repro.kernels.ops), which TestXlaTwin covers."""
 
     @pytest.mark.parametrize(
@@ -104,7 +104,7 @@ class TestPallasKernel:
     def test_encode_matches_oracle(self, k, p, nbytes):
         rng = np.random.default_rng(k * 1000 + p)
         data = rng.integers(0, 256, size=(k, nbytes), dtype=np.uint8)
-        got = np.asarray(ops.encode_chunks(data, p, use_kernel=True, pallas=True))
+        got = np.asarray(ops.encode_chunks(data, p, use_kernel=True, interpret=True))
         want = np.asarray(ops.encode_chunks(data, p, use_kernel=False))
         np.testing.assert_array_equal(got, want)
 
@@ -112,7 +112,7 @@ class TestPallasKernel:
     def test_unaligned_sizes_padded_correctly(self, nbytes):
         rng = np.random.default_rng(nbytes)
         data = rng.integers(0, 256, size=(4, nbytes), dtype=np.uint8)
-        got = np.asarray(ops.encode_chunks(data, 2, use_kernel=True, pallas=True))
+        got = np.asarray(ops.encode_chunks(data, 2, use_kernel=True, interpret=True))
         want = np.asarray(ops.encode_chunks(data, 2, use_kernel=False))
         assert got.shape == (2, nbytes)
         np.testing.assert_array_equal(got, want)
@@ -121,8 +121,8 @@ class TestPallasKernel:
     def test_block_size_invariance(self, block):
         rng = np.random.default_rng(block)
         data = rng.integers(0, 256, size=(5, 4096), dtype=np.uint8)
-        a = np.asarray(ops.encode_chunks(data, 3, block_bytes=block, pallas=True))
-        b = np.asarray(ops.encode_chunks(data, 3, block_bytes=2048, pallas=True))
+        a = np.asarray(ops.encode_chunks(data, 3, block_bytes=block, interpret=True))
+        b = np.asarray(ops.encode_chunks(data, 3, block_bytes=2048, interpret=True))
         np.testing.assert_array_equal(a, b)
 
     def test_decode_kernel_matches_oracle(self):
@@ -134,7 +134,7 @@ class TestPallasKernel:
         rows = np.array([0, 2, 5, 6, 7])  # mix of data+parity rows
         got = np.asarray(
             ops.decode_chunks(all_chunks[rows], rows, k, p,
-                              use_kernel=True, pallas=True)
+                              use_kernel=True, interpret=True)
         )
         want = np.asarray(
             ops.decode_chunks(all_chunks[rows], rows, k, p, use_kernel=False)
@@ -151,7 +151,7 @@ class TestXlaTwin:
     def test_encode_matches_oracle(self, k, p, nbytes):
         rng = np.random.default_rng(k * 7 + nbytes)
         data = rng.integers(0, 256, size=(k, nbytes), dtype=np.uint8)
-        got = np.asarray(ops.encode_chunks(data, p, use_kernel=True, pallas=False))
+        got = np.asarray(ops.encode_chunks(data, p, use_kernel=True))
         want = np.asarray(ops.encode_chunks(data, p, use_kernel=False))
         np.testing.assert_array_equal(got, want)
 
@@ -162,7 +162,7 @@ class TestXlaTwin:
         wide = rng.integers(
             0, 256, size=(4, ops.EC_TILE_BLOCKS * 2048 * 5), dtype=np.uint8
         )
-        got = np.asarray(ops.encode_chunks(wide, 2, use_kernel=True, pallas=False))
+        got = np.asarray(ops.encode_chunks(wide, 2, use_kernel=True))
         want = np.asarray(ops.encode_chunks(wide, 2, use_kernel=False))
         np.testing.assert_array_equal(got, want)
 

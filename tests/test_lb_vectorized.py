@@ -31,12 +31,8 @@ from repro.core import (
     create_scheduler,
     get_spec,
 )
-from repro.core import lb_kernel
 from repro.storage import make_node_set, make_trace
 
-needs_jax = pytest.mark.skipif(
-    not lb_kernel.kernel_available(), reason="jax unavailable"
-)
 
 
 def forced_kernel_scheduler():
@@ -138,14 +134,12 @@ class TestGoldenPlacements:
         want = [Placement(k, p, ids) for k, p, ids in GOLDEN[key]]
         assert got == want
 
-    @needs_jax
     @pytest.mark.parametrize("key", GOLDEN_KEYS)
     def test_kernel_matches_golden(self, key):
         got = self._run(*key, forced_kernel_scheduler())
         want = [Placement(k, p, ids) for k, p, ids in GOLDEN[key]]
         assert got == want
 
-    @needs_jax
     @pytest.mark.parametrize("key", GOLDEN_KEYS)
     def test_batched_place_many_matches_golden(self, key):
         nodeset, seed = key
@@ -158,7 +152,6 @@ class TestGoldenPlacements:
         assert got == want
 
 
-@needs_jax
 class TestKernelOracleEquivalence:
     """Kernel decisions == scalar oracle decisions, bit for bit."""
 
@@ -295,7 +288,6 @@ class TestKernelOracleEquivalence:
         assert sched.smin_mb == smin0
 
 
-@needs_jax
 class TestSummationOrderPolicy:
     """The penalty prefix sums are sequential on both paths: ulp-level
     agreement, not just same-argmin agreement."""

@@ -29,7 +29,6 @@ def setup():
 
 
 def _mesh():
-    # AxisType-compatible on jax <= 0.4.x (no axis_types kwarg there).
     return make_mesh_compat((1, 1), ("data", "model"))
 
 

@@ -31,16 +31,8 @@ from repro.core import (
     get_spec,
     scheduler_names,
 )
-from repro.core import greedy_kernel, lb_kernel, prefilter, sc_kernel
+from repro.core import prefilter
 
-needs_jax = pytest.mark.skipif(
-    not (
-        sc_kernel.kernel_available()
-        and greedy_kernel.kernel_available()
-        and lb_kernel.kernel_available()
-    ),
-    reason="jax unavailable",
-)
 
 ALL_REGISTERED = sorted(set(scheduler_names()) | set(SCHEDULER_NAMES))
 
@@ -107,7 +99,6 @@ def assert_decisions_match(got, want, label):
 LB_CAP = 8  # instance override; lb_batch needs m >= 3
 
 
-@needs_jax
 @pytest.mark.parametrize("delta", [-1, 0, 1])
 class TestLBBoundary:
     def _pair(self):
@@ -145,7 +136,6 @@ class TestLBBoundary:
             assert st.get("engaged", 0) == 0
 
 
-@needs_jax
 class TestLBFallback:
     def test_failed_sufficiency_rows_rerun_unfiltered(self):
         # Near-hopeless nodes + a hard target: the filtered grid's found
@@ -180,7 +170,6 @@ SC_BUDGET = 16  # instance override; sc_cap(16) == rung(17) == 24
 SC_CAP = prefilter.sc_cap(SC_BUDGET)
 
 
-@needs_jax
 @pytest.mark.parametrize("delta", [-1, 0, 1])
 @pytest.mark.parametrize("ties", [False, True])
 class TestSCBoundary:
@@ -208,7 +197,6 @@ class TestSCBoundary:
 LU_CAP = 6  # SCAN_CAP override
 
 
-@needs_jax
 @pytest.mark.parametrize("delta", [-1, 0, 1])
 class TestLeastUsedBoundary:
     def test_matches_scalar_oracle_at_the_cut(self, delta):
@@ -237,7 +225,6 @@ class TestLeastUsedBoundary:
             assert prefilter.stats()["greedy_least_used"]["fallback"] > 0
 
 
-@needs_jax
 @pytest.mark.parametrize("seed", [0, 1, 2])
 @pytest.mark.parametrize("name", ALL_REGISTERED)
 class TestRegistrySweep:

@@ -81,7 +81,6 @@ class TestJaxprFlops:
 def _compile(fn, *args, mesh_axes=None, in_shardings=None):
     if in_shardings is None:
         return jax.jit(fn).lower(*args).compile()
-    # AxisType-compatible on jax <= 0.4.x (no axis_types kwarg there).
     from repro.launch.mesh import make_mesh_compat
 
     mesh = make_mesh_compat((jax.device_count(),), ("x",))

@@ -20,12 +20,8 @@ from repro.core import (
     create_scheduler,
     get_spec,
 )
-from repro.core import sc_kernel
 from repro.storage import make_node_set, make_trace
 
-needs_jax = pytest.mark.skipif(
-    not sc_kernel.kernel_available(), reason="jax unavailable"
-)
 
 
 def forced_kernel_scheduler() -> DRexSC:
@@ -82,14 +78,12 @@ class TestGoldenPlacements:
         want = [Placement(k, p, ids) for k, p, ids in self.GOLDEN[key]]
         assert got == want
 
-    @needs_jax
     @pytest.mark.parametrize("key", sorted(GOLDEN))
     def test_kernel_matches_golden(self, key):
         got = self._run(*key, forced_kernel_scheduler())
         want = [Placement(k, p, ids) for k, p, ids in self.GOLDEN[key]]
         assert got == want
 
-    @needs_jax
     @pytest.mark.parametrize("key", sorted(GOLDEN))
     def test_batched_place_many_matches_golden(self, key):
         nodeset, seed = key
@@ -100,7 +94,6 @@ class TestGoldenPlacements:
         assert got == want
 
 
-@needs_jax
 class TestKernelOracleEquivalence:
     """Kernel decisions == scalar oracle decisions, bit for bit."""
 

@@ -15,9 +15,14 @@ import numpy as np
 import pytest
 
 from repro.core import shapes
-from repro.core import lb_kernel
 from repro.core.shapes import ShapeBucketer, rung
-from repro.core import ClusterView, DataItem, StorageNode, create_scheduler
+from repro.core import (
+    ClusterView,
+    DataItem,
+    PlacementEngine,
+    StorageNode,
+    create_scheduler,
+)
 
 
 class TestRungLadder:
@@ -92,11 +97,6 @@ class TestCompileCensus:
         assert set(stats) == {"queries", "band_hits", "kernels"}
 
 
-needs_jax = pytest.mark.skipif(
-    not lb_kernel.kernel_available(), reason="jax unavailable"
-)
-
-
 def churn_cluster(n: int, seed: int = 0) -> ClusterView:
     rng = np.random.default_rng(seed)
     return ClusterView.from_nodes(
@@ -113,7 +113,6 @@ def churn_cluster(n: int, seed: int = 0) -> ClusterView:
     )
 
 
-@needs_jax
 class TestRecompileBudgetUnderChurn:
     """A node_join/node_heal churn sequence must stay within the bucket
     budget — the compile census counts every distinct static signature
@@ -157,3 +156,98 @@ class TestRecompileBudgetUnderChurn:
         got = sched.place(item, cluster)
         assert got.placement == want.placement
         assert got.candidates_considered == want.candidates_considered
+
+
+class TestKernelsEngaged:
+    """The decision kernels are a hard dependency: a batched placement
+    under the default dispatch policy must reach every kernel module.
+    An import or dispatch break then fails here instead of silently
+    sending every decision to the scalar oracle."""
+
+    #: scheduler -> census name of the kernel its batches must reach
+    #: (both greedy schedulers live in ``repro.core.greedy_kernel``).
+    KERNELS = {
+        "drex_sc": "sc_kernel",
+        "drex_lb": "lb_kernel",
+        "greedy_min_storage": "min_storage_kernel",
+        "greedy_least_used": "least_used_kernel",
+    }
+
+    @pytest.mark.parametrize("name", sorted(KERNELS))
+    def test_place_many_records_kernel_signature(self, name):
+        kernel = self.KERNELS[name]
+        # 200 live nodes clear every batch crossover (GreedyLeastUsed's
+        # is the largest, KERNEL_MIN_NODES_BATCH = 192).
+        engine = PlacementEngine(churn_cluster(200), name, auto_commit=False)
+        items = [DataItem(i, 50.0 + i, float(i), 365.0, 0.999) for i in range(8)]
+
+        def calls():
+            return shapes.compile_cache_stats()["kernels"].get(
+                kernel, {"calls": 0}
+            )["calls"]
+
+        before = calls()
+        records = engine.place_many(items)
+        assert all(r.ok for r in records)
+        assert calls() > before
+        assert shapes.issued_shapes(kernel)
+        devices = shapes.kernel_devices()[kernel]
+        assert devices and all(
+            d.startswith(shapes.DECISION_PLATFORM + ":") for d in devices
+        )
+
+
+class TestDecisionScope:
+    """The one placement choice for the float64 decision kernels."""
+
+    def test_scope_sets_x64_and_decision_device(self):
+        import jax
+
+        with shapes.decision_scope():
+            assert jax.config.jax_enable_x64
+            x = jax.numpy.zeros(3)
+        assert x.dtype == np.float64
+        assert {d.platform for d in x.devices()} == {shapes.DECISION_PLATFORM}
+        assert not jax.config.jax_enable_x64
+
+    def test_record_device_names_platform_and_id(self):
+        import jax
+
+        b = ShapeBucketer()
+        b.record_device("k", jax.numpy.zeros(2))
+        dev = jax.devices()[0]
+        assert b.kernel_devices() == {"k": (f"{dev.platform}:{dev.id}",)}
+        b.reset()
+        assert b.kernel_devices() == {}
+
+
+class TestCompilationCacheDir:
+    """The persistent compile cache lives where the environment says,
+    else at one fixed path inside the checkout — never relative to the
+    working directory."""
+
+    def test_checkout_dir_ignores_cwd(self, monkeypatch, tmp_path):
+        import pathlib
+
+        from repro.core import jitcache
+
+        monkeypatch.delenv(jitcache.ENV_DIR, raising=False)
+        monkeypatch.chdir(tmp_path)
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert jitcache.cache_dir() == root / "results" / ".jax_cache"
+        assert jitcache.status()["source"] == "checkout"
+
+    def test_env_dir_wins(self, monkeypatch, tmp_path):
+        from repro.core import jitcache
+
+        monkeypatch.setenv(jitcache.ENV_DIR, str(tmp_path))
+        assert jitcache.cache_dir() == tmp_path
+        assert jitcache.status()["source"] == "env"
+
+    def test_cache_is_configured(self):
+        import jax
+
+        from repro.core import jitcache
+
+        assert jax.config.jax_compilation_cache_dir == str(jitcache.cache_dir())
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
