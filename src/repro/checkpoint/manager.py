@@ -31,7 +31,6 @@ import dataclasses
 import io
 import json
 import threading
-import time
 from collections import deque
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Optional
@@ -39,6 +38,7 @@ from typing import Any, Optional
 import jax
 import numpy as np
 
+from repro import telemetry
 from repro.core import BatchContext, DataItem, Placement, PlacementEngine, Scheduler
 from repro.ec import ECCodec, plan_cohorts
 from repro.train.step import TrainState
@@ -123,7 +123,14 @@ class DRexCheckpointer:
 
         Placement decisions for all groups are made against the cluster
         view at the start of the save (one ``place_many`` batch) — the
-        fabric's byte accounting still updates as chunks land."""
+        fabric's byte accounting still updates as chunks land.
+
+        Each stage is a :func:`repro.telemetry.span` whose request is
+        ``step`` (README "Telemetry facade" lists them)."""
+        with telemetry.span("ckpt.save", request=step) as sp:
+            return self._save(state, step, sp)
+
+    def _save(self, state: TrainState, step: int, save_span) -> dict:
         leaves, treedef = jax.tree.flatten(state)
         # The tree structure is reconstructed from a like-state at restore
         # (shapes/dtypes per leaf live in the manifest).
@@ -138,22 +145,35 @@ class DRexCheckpointer:
             if leaf is None:
                 manifest["leaves"].append(None)
                 continue
-            arr = np.asarray(jax.device_get(leaf))
+            with telemetry.span("ckpt.d2h") as sp:
+                arr = np.asarray(jax.device_get(leaf))
+                # a host leaf (save_async hands those in) is not copied
+                sp.nbytes = arr.nbytes if isinstance(leaf, jax.Array) else 0
+            save_span.nbytes += arr.nbytes
             manifest["leaves"].append(
                 {"shape": list(arr.shape), "dtype": str(arr.dtype), "groups": []}
             )
-            raw = arr.tobytes()
+            with telemetry.span("ckpt.split") as sp:
+                raw = arr.tobytes()
+                sp.nbytes = len(raw)
+                for off in range(0, max(len(raw), 1), max_bytes):
+                    payload = raw[off : off + max_bytes]
+                    padded = _pad_to_bucket(payload)
+                    payloads.append(padded)
+                    orig_lens.append(len(payload))
+                    slots.append((li, off // max_bytes))
+                    # a slice of all of ``raw`` is ``raw`` itself; padding
+                    # writes the zero filler and then the padded payload
+                    if payload is not raw:
+                        sp.nbytes += len(payload)
+                    if padded is not payload:
+                        sp.nbytes += 2 * len(padded) - len(payload)
             with self._meta_lock:
                 self.stats["bytes_raw"] += len(raw)
-            for off in range(0, max(len(raw), 1), max_bytes):
-                payload = raw[off : off + max_bytes]
-                payloads.append(_pad_to_bucket(payload))
-                orig_lens.append(len(payload))
-                slots.append((li, off // max_bytes))
         # 2. One placement batch: groups share retention and reliability
         # target, so the engine's batch context amortizes the scheduler's
         # reliability DP across all groups of this save.
-        with self._place_lock:
+        with telemetry.span("ckpt.place"), self._place_lock:
             items = []
             for payload in payloads:
                 self._item_counter += 1
@@ -198,8 +218,9 @@ class DRexCheckpointer:
                 except Exception:
                     pass
             raise
-        while pending:
-            pending.popleft().result()
+        with telemetry.span("ckpt.put_wait"):
+            while pending:
+                pending.popleft().result()
         # 4. Manifest in original (leaf, part) order.
         for g, (li, _part) in zip(groups, slots):
             manifest["leaves"][li]["groups"].append(dataclasses.asdict(g))
@@ -217,10 +238,10 @@ class DRexCheckpointer:
         for wave in waves:
             k, p = placements[wave[0]].k, placements[wave[0]].p
             codec = ECCodec(k, p, use_kernel=policy.use_kernel)
-            t0 = time.perf_counter()
-            chunk_mats = codec.encode_many([payloads[i] for i in wave])
+            with telemetry.span("ckpt.encode") as sp:
+                chunk_mats = codec.encode_many([payloads[i] for i in wave])
             with self._meta_lock:
-                self.stats["encode_s"] += time.perf_counter() - t0
+                self.stats["encode_s"] += sp.seconds
             entries = []
             for i, chunks in zip(wave, chunk_mats):
                 li, part = slots[i]
@@ -232,20 +253,24 @@ class DRexCheckpointer:
                 groups[i] = g
                 entries.append((g, chunks))
             if policy.pipeline_workers == 0:
-                self._put_wave(entries)
+                self._put_wave(entries, step)
             else:
-                pending.append(self._io_pool.submit(self._put_wave, entries))
+                pending.append(self._io_pool.submit(self._put_wave, entries, step))
                 # double buffer: at most 2 waves of chunks in flight
-                while len(pending) > 2:
-                    pending.popleft().result()
+                if len(pending) > 2:
+                    with telemetry.span("ckpt.put_wait"):
+                        while len(pending) > 2:
+                            pending.popleft().result()
 
-    def _put_wave(self, entries: list[tuple[_Group, np.ndarray]]) -> None:
+    def _put_wave(self, entries: list[tuple[_Group, np.ndarray]], step: int) -> None:
         """Land one wave's chunks on the fabric (runs on the I/O pool)."""
         stored = 0.0
-        for g, chunks in entries:
-            for row, node in enumerate(g.node_ids):
-                self.fabric.put(node, f"{g.key}_r{row}", chunks[row].tobytes())
-                stored += chunks.shape[1]
+        with telemetry.span("ckpt.put", request=step) as sp:
+            for g, chunks in entries:
+                for row, node in enumerate(g.node_ids):
+                    self.fabric.put(node, f"{g.key}_r{row}", chunks[row].tobytes())
+                    stored += chunks.shape[1]
+            sp.nbytes = int(stored)
         with self._meta_lock:
             self.stats["bytes_stored"] += stored
 

@@ -20,6 +20,7 @@ from typing import Sequence
 
 import numpy as np
 
+from repro import telemetry
 from repro.kernels import ops as kops
 
 __all__ = [
@@ -79,14 +80,17 @@ class ECCodec:
         sees the cohort concatenated along the byte axis).  Returns the
         (N, chunk_len_i) chunk matrices in input order, bit-identical to
         per-item :meth:`encode`."""
-        datas = [self._data_matrix(p) for p in payloads]
+        with telemetry.span("codec.stage") as sp:
+            datas = [self._data_matrix(p) for p in payloads]
+            sp.nbytes = sum(d.nbytes for d in datas)
         parities = kops.encode_chunks_many(
             datas, self.p, use_kernel=self.use_kernel
         )
-        return [
-            np.concatenate([d, np.asarray(par)], axis=0)
-            for d, par in zip(datas, parities)
-        ]
+        with telemetry.span("codec.assemble", self.n * sum(d.shape[1] for d in datas)):
+            return [
+                np.concatenate([d, np.asarray(par)], axis=0)
+                for d, par in zip(datas, parities)
+            ]
 
     def _select_rows(
         self, chunks: np.ndarray, rows: np.ndarray

@@ -46,6 +46,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.core import shapes as _shapes
 from repro.ec import gf256
 from .rs_bitmatmul import DEFAULT_BLOCK_BYTES, gf_bitmatmul
@@ -276,18 +277,23 @@ def _matmul_concat(
         if w == 0:
             outs[i] = np.zeros((out_rows, 0), dtype=np.uint8)
     if live:
-        cat = jnp.asarray(
-            np.concatenate([mats[i] for i in live], axis=1), dtype=jnp.uint8
-        )
-        total = cat.shape[1]
-        if use_kernel:
-            padded, _ = _pad_to_bucket(cat, block_bytes)
-            out = _bitmatmul(
-                bitm, padded, block_bytes=block_bytes, interpret=interpret
-            )[:, :total]
-        else:
-            out = _ref.gf_matmul_ref(jnp.asarray(gf_matrix), cat)
-        out = np.asarray(out)
+        with telemetry.span("codec.concat") as sp:
+            host = np.concatenate([mats[i] for i in live], axis=1)
+            sp.nbytes = host.nbytes
+        total = host.shape[1]
+        with telemetry.span("codec.h2d", host.nbytes):
+            cat = jnp.asarray(host, dtype=jnp.uint8)
+            if use_kernel:
+                padded, _ = _pad_to_bucket(cat, block_bytes)
+                out = _bitmatmul(
+                    bitm, padded, block_bytes=block_bytes, interpret=interpret
+                )[:, :total]
+            else:
+                out = _ref.gf_matmul_ref(jnp.asarray(gf_matrix), cat)
+        with telemetry.span("codec.wait"):
+            out.block_until_ready()
+        with telemetry.span("codec.d2h", out_rows * total):
+            out = np.asarray(out)
         off = 0
         for i in live:
             outs[i] = out[:, off : off + widths[i]]

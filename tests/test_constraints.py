@@ -313,6 +313,7 @@ class TestTelemetryFacade:
             "compile_cache",
             "engine",
             "jit_cache",
+            "spans",
         }
 
     def test_snapshot_includes_engine_counters(self):
