@@ -16,7 +16,9 @@ checkpoint are placed in ONE ``place_many`` batch (one shared
 encoded in per-(K, P) cohort waves through ``ECCodec.encode_many`` (one
 kernel launch per wave), and each wave's fabric ``put`` overlaps the
 *next* wave's encode through a multi-worker I/O pool (double-buffered —
-at most two waves of chunks are in flight, bounding peak memory).
+at most two waves of chunks are in flight, bounding peak memory).  Each
+wave is staged, coded and put from one host wave buffer that the
+checkpointer reuses from wave to wave and from save to save.
 ``pipeline_workers=0`` recovers the legacy serial path (per-group encode
 then put), which benchmarks/fig13 uses as the upload baseline.
 
@@ -60,7 +62,52 @@ class CheckpointPolicy:
     pipeline_workers: int = 2
     #: max groups fused into one encode launch; also the wave size the
     #: pipeline double-buffers (bounds peak chunk memory to ~2 waves).
+    #: The checkpointer keeps up to three wave buffers (two waves in
+    #: flight, one being encoded), each of the largest wave's N x W bytes,
+    #: alive between saves; before, a save allocated as much afresh and
+    #: freed it.  A wave is at most this many groups x the bucket of
+    #: ``item_mb`` x N/K: 3 x 1.61 GB held at the defaults and (K, P) =
+    #: (4, 2).  One chip's shard of 8-way FSDP Yi-6B (groups of at most a
+    #: 32 MiB bucket) holds 3 x 358.6 MB.
     encode_wave_groups: int = 16
+
+
+#: waves whose fabric puts may be in flight while the next one encodes.
+_WAVES_IN_FLIGHT = 2
+
+
+class _WaveBuffers:
+    """Free list of the host wave buffers ``ECCodec.encode_many`` stages,
+    codes and assembles a wave in.  A buffer is handed back only once
+    nothing reads it any more (its wave's puts are done); the list keeps
+    at most ``_WAVES_IN_FLIGHT + 1`` of them, each of at least the largest
+    wave seen, so a save shaped like an earlier one allocates nothing."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._free: list[np.ndarray] = []
+        self._nbytes = 0
+
+    def reserve(self, nbytes: int) -> None:
+        """Size every later allocation to at least ``nbytes``."""
+        with self._lock:
+            self._nbytes = max(self._nbytes, nbytes)
+
+    def take(self, nbytes: int) -> np.ndarray:
+        with telemetry.span("codec.wave_buffer"):
+            with self._lock:
+                while self._free:
+                    buf = self._free.pop()
+                    if buf.size >= nbytes:
+                        return buf
+                size = max(self._nbytes, nbytes)
+            with telemetry.span("codec.wave_alloc"):
+                return np.empty(size, dtype=np.uint8)
+
+    def give(self, buf: np.ndarray) -> None:
+        with self._lock:
+            if len(self._free) <= _WAVES_IN_FLIGHT and buf.size >= self._nbytes:
+                self._free.append(buf)
 
 
 @dataclasses.dataclass
@@ -112,6 +159,7 @@ class DRexCheckpointer:
         self._place_lock = threading.Lock()
         self._meta_lock = threading.Lock()
         self._item_counter = 0
+        self._wave_bufs = _WaveBuffers()
         self.stats: dict[str, float] = {
             "bytes_raw": 0.0, "bytes_stored": 0.0, "encode_s": 0.0, "place_s": 0.0,
         }
@@ -205,22 +253,22 @@ class DRexCheckpointer:
         for (_kp, idxs) in plan_cohorts([(pl.k, pl.p) for pl in placements]):
             for w in range(0, len(idxs), wave_size):
                 waves.append(idxs[w : w + wave_size])
-        pending: deque[Future] = deque()
+        pending: deque[tuple[Future, np.ndarray]] = deque()
         try:
             self._encode_waves(
                 waves, payloads, placements, slots, orig_lens, groups,
                 step, pending,
             )
+            with telemetry.span("ckpt.put_wait"):
+                while pending:
+                    self._settle(pending.popleft())
         except BaseException:
             while pending:  # no orphaned background puts behind an error
                 try:
-                    pending.popleft().result()
+                    self._settle(pending.popleft())
                 except Exception:
                     pass
             raise
-        with telemetry.span("ckpt.put_wait"):
-            while pending:
-                pending.popleft().result()
         # 4. Manifest in original (leaf, part) order.
         for g, (li, _part) in zip(groups, slots):
             manifest["leaves"][li]["groups"].append(dataclasses.asdict(g))
@@ -233,13 +281,25 @@ class DRexCheckpointer:
         self, waves, payloads, placements, slots, orig_lens, groups,
         step, pending,
     ) -> None:
-        """Encode each wave and hand its chunks to the I/O pool."""
+        """Encode each wave and hand its chunks to the I/O pool.  A wave's
+        buffer goes back to the free list when its puts are done."""
         policy = self.policy
-        for wave in waves:
-            k, p = placements[wave[0]].k, placements[wave[0]].p
-            codec = ECCodec(k, p, use_kernel=policy.use_kernel)
+        codecs = [
+            ECCodec(placements[w[0]].k, placements[w[0]].p, use_kernel=policy.use_kernel)
+            for w in waves
+        ]
+        sizes = [
+            codec.wave_nbytes([len(payloads[i]) for i in wave])
+            for codec, wave in zip(codecs, waves)
+        ]
+        self._wave_bufs.reserve(max(sizes, default=0))
+        for wave, codec, nbytes in zip(waves, codecs, sizes):
+            k, p = codec.k, codec.p
             with telemetry.span("ckpt.encode") as sp:
-                chunk_mats = codec.encode_many([payloads[i] for i in wave])
+                buf = self._wave_bufs.take(nbytes)
+                # a buffer whose encode failed is dropped, not reused: a
+                # device array made from it may still read it
+                chunk_mats = codec.encode_many([payloads[i] for i in wave], out=buf)
             with self._meta_lock:
                 self.stats["encode_s"] += sp.seconds
             entries = []
@@ -253,14 +313,25 @@ class DRexCheckpointer:
                 groups[i] = g
                 entries.append((g, chunks))
             if policy.pipeline_workers == 0:
-                self._put_wave(entries, step)
+                try:
+                    self._put_wave(entries, step)
+                finally:
+                    self._wave_bufs.give(buf)
             else:
-                pending.append(self._io_pool.submit(self._put_wave, entries, step))
+                pending.append((self._io_pool.submit(self._put_wave, entries, step), buf))
                 # double buffer: at most 2 waves of chunks in flight
-                if len(pending) > 2:
+                if len(pending) > _WAVES_IN_FLIGHT:
                     with telemetry.span("ckpt.put_wait"):
-                        while len(pending) > 2:
-                            pending.popleft().result()
+                        while len(pending) > _WAVES_IN_FLIGHT:
+                            self._settle(pending.popleft())
+
+    def _settle(self, wave: tuple[Future, np.ndarray]) -> None:
+        """Wait for a wave's puts, then free its buffer (even if they failed)."""
+        fut, buf = wave
+        try:
+            fut.result()
+        finally:
+            self._wave_bufs.give(buf)
 
     def _put_wave(self, entries: list[tuple[_Group, np.ndarray]], step: int) -> None:
         """Land one wave's chunks on the fabric (runs on the I/O pool)."""

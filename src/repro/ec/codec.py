@@ -38,6 +38,18 @@ def _as_bytes_array(payload) -> np.ndarray:
     return np.asarray(payload, dtype=np.uint8).ravel()
 
 
+def _stage_rows(rows: np.ndarray, buf: np.ndarray) -> None:
+    """Copy one payload into its (K, chunk_len) block of a wave buffer's
+    data rows, row by row; only the short tail is zeroed."""
+    k, clen = rows.shape
+    full, rem = divmod(buf.size, clen)
+    rows[:full] = buf[: full * clen].reshape(full, clen)
+    if full < k:
+        rows[full, :rem] = buf[full * clen :]
+        rows[full, rem:] = 0
+        rows[full + 1 :] = 0
+
+
 @dataclasses.dataclass(frozen=True)
 class ECCodec:
     k: int
@@ -73,24 +85,50 @@ class ECCodec:
         )
         return np.concatenate([data, parity], axis=0)
 
-    def encode_many(self, payloads: Sequence) -> list[np.ndarray]:
+    def wave_nbytes(self, sizes: Sequence[int]) -> int:
+        """Bytes of the wave buffer :meth:`encode_many` needs for payloads
+        of these sizes: N rows of the summed chunk lengths."""
+        return self.n * sum(self.chunk_len(s) for s in sizes)
+
+    def encode_many(self, payloads: Sequence, out: np.ndarray | None = None) -> list[np.ndarray]:
         """Encode a cohort of payloads in ONE kernel launch.
 
         Payload lengths may differ (the code is columnwise; the kernel
-        sees the cohort concatenated along the byte axis).  Returns the
-        (N, chunk_len_i) chunk matrices in input order, bit-identical to
+        sees the cohort side by side along the byte axis).  The cohort
+        lives in one (N, W) wave buffer, W the summed chunk lengths: the
+        payloads are staged into its K data rows, which go to the device
+        as they are, and the parity comes back into its P parity rows.
+        ``out`` is that buffer, a flat uint8 array of at least
+        :meth:`wave_nbytes` bytes that the caller owns and may reuse once
+        it is done with the chunks (a fresh one when None).
+
+        Returns the (N, chunk_len_i) chunk matrices in input order, each a
+        column view of the buffer (every row contiguous), bit-identical to
         per-item :meth:`encode`."""
-        with telemetry.span("codec.stage") as sp:
-            datas = [self._data_matrix(p) for p in payloads]
-            sp.nbytes = sum(d.nbytes for d in datas)
-        parities = kops.encode_chunks_many(
-            datas, self.p, use_kernel=self.use_kernel
-        )
-        with telemetry.span("codec.assemble", self.n * sum(d.shape[1] for d in datas)):
-            return [
-                np.concatenate([d, np.asarray(par)], axis=0)
-                for d, par in zip(datas, parities)
-            ]
+        bufs = [_as_bytes_array(p) for p in payloads]
+        clens = [self.chunk_len(b.size) for b in bufs]
+        width = sum(clens)
+        if out is None:
+            out = np.empty(self.n * width, dtype=np.uint8)
+        if (out.dtype != np.uint8 or out.ndim != 1 or not out.flags.c_contiguous
+                or out.size < self.n * width):
+            raise ValueError(
+                f"wave buffer must be flat contiguous uint8 of at least "
+                f"{self.n * width} bytes, got {out.dtype} {out.shape}"
+            )
+        wave = out[: self.n * width].reshape(self.n, width)
+        offs = np.cumsum([0] + clens).tolist()
+        with telemetry.span("codec.stage", self.k * width):
+            for buf, a, b in zip(bufs, offs, offs[1:]):
+                if b > a:
+                    _stage_rows(wave[: self.k, a:b], buf)
+        if width:
+            (parity,) = kops.encode_chunks_many(
+                [wave[: self.k]], self.p, use_kernel=self.use_kernel
+            )
+            with telemetry.span("codec.assemble", self.p * width):
+                wave[self.k :] = parity
+        return [wave[:, a:b] for a, b in zip(offs, offs[1:])]
 
     def _select_rows(
         self, chunks: np.ndarray, rows: np.ndarray

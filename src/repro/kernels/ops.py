@@ -259,7 +259,43 @@ def decode_chunks(
 
 # -- multi-item API: one launch per cohort -----------------------------------
 
-def _matmul_concat(
+def _concat(mats: list[np.ndarray]) -> np.ndarray:
+    """A cohort's (K, B_i) matrices side by side in one fresh host matrix."""
+    with telemetry.span("codec.concat") as sp:
+        host = np.concatenate(mats, axis=1)
+        sp.nbytes = host.nbytes
+    return host
+
+
+def _launch(
+    host: np.ndarray,
+    gf_matrix: np.ndarray,
+    bitm,
+    out_rows: int,
+    *,
+    block_bytes: int,
+    use_kernel: bool,
+    interpret: bool,
+) -> np.ndarray:
+    """Apply one coding matrix to a host (K, W) matrix in one launch and
+    bring the (out_rows, W) result back to the host."""
+    total = host.shape[1]
+    with telemetry.span("codec.h2d", host.nbytes):
+        cat = jnp.asarray(host, dtype=jnp.uint8)
+        if use_kernel:
+            padded, _ = _pad_to_bucket(cat, block_bytes)
+            out = _bitmatmul(
+                bitm, padded, block_bytes=block_bytes, interpret=interpret
+            )[:, :total]
+        else:
+            out = _ref.gf_matmul_ref(jnp.asarray(gf_matrix), cat)
+    with telemetry.span("codec.wait"):
+        out.block_until_ready()
+    with telemetry.span("codec.d2h", out_rows * total):
+        return np.asarray(out)
+
+
+def _matmul_many(
     mats: list[np.ndarray],
     gf_matrix: np.ndarray,
     bitm,
@@ -269,35 +305,20 @@ def _matmul_concat(
     use_kernel: bool,
     interpret: bool,
 ) -> list[np.ndarray]:
-    """Apply one coding matrix to many (K, B_i) matrices in one launch."""
-    widths = [m.shape[1] for m in mats]
-    outs: list = [None] * len(mats)
-    live = [i for i, w in enumerate(widths) if w > 0]
-    for i, w in enumerate(widths):
-        if w == 0:
-            outs[i] = np.zeros((out_rows, 0), dtype=np.uint8)
-    if live:
-        with telemetry.span("codec.concat") as sp:
-            host = np.concatenate([mats[i] for i in live], axis=1)
-            sp.nbytes = host.nbytes
-        total = host.shape[1]
-        with telemetry.span("codec.h2d", host.nbytes):
-            cat = jnp.asarray(host, dtype=jnp.uint8)
-            if use_kernel:
-                padded, _ = _pad_to_bucket(cat, block_bytes)
-                out = _bitmatmul(
-                    bitm, padded, block_bytes=block_bytes, interpret=interpret
-                )[:, :total]
-            else:
-                out = _ref.gf_matmul_ref(jnp.asarray(gf_matrix), cat)
-        with telemetry.span("codec.wait"):
-            out.block_until_ready()
-        with telemetry.span("codec.d2h", out_rows * total):
-            out = np.asarray(out)
-        off = 0
-        for i in live:
-            outs[i] = out[:, off : off + widths[i]]
-            off += widths[i]
+    """Apply one coding matrix to many (K, B_i) matrices in one launch.
+    A lone non-empty matrix (a codec wave buffer's data rows) is launched
+    as it is; more are laid side by side in one host matrix first."""
+    live = [m for m in mats if m.shape[1]]
+    if not live:
+        return [np.zeros((out_rows, 0), dtype=np.uint8) for _ in mats]
+    out = _launch(
+        live[0] if len(live) == 1 else _concat(live), gf_matrix, bitm, out_rows,
+        block_bytes=block_bytes, use_kernel=use_kernel, interpret=interpret,
+    )
+    outs, off = [], 0
+    for m in mats:
+        outs.append(out[:, off : off + m.shape[1]])
+        off += m.shape[1]
     return outs
 
 
@@ -313,8 +334,10 @@ def encode_chunks_many(
 
     The cohort is stacked along the byte axis into ONE kernel launch
     (byte lengths may differ — the code is columnwise); results are
-    bit-identical to per-item :func:`encode_chunks`.  Returns a list of
-    (P, B_i) numpy arrays in input order."""
+    bit-identical to per-item :func:`encode_chunks`.  A cohort of one
+    matrix is launched without a host copy (as under
+    :func:`decode_chunks_many`), so a caller that staged its items side
+    by side (``ECCodec.encode_many``) passes that one matrix.  Returns a list of (P, B_i) numpy arrays in input order."""
     mats = [np.asarray(d, dtype=np.uint8) for d in data_chunks_list]
     if not mats:
         return []
@@ -326,7 +349,7 @@ def encode_chunks_many(
                 "first — see repro.ec.codec.plan_cohorts)"
             )
     cauchy, bitm = _encode_matrices(k, p)
-    return _matmul_concat(
+    return _matmul_many(
         mats, cauchy, bitm, p,
         block_bytes=block_bytes, use_kernel=use_kernel, interpret=interpret,
     )
@@ -355,7 +378,7 @@ def decode_chunks_many(
     outs: list = [None] * len(mats)
     for rows_key, idxs in by_pattern.items():
         dec, bitm = _decode_matrices(k, p, rows_key)
-        got = _matmul_concat(
+        got = _matmul_many(
             [mats[i] for i in idxs], dec, bitm, k,
             block_bytes=block_bytes, use_kernel=use_kernel, interpret=interpret,
         )

@@ -1,6 +1,8 @@
 """EC-protected checkpointing tests: save/restore, node failures, repair,
 async path, GC, trainer integration, elastic restore."""
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -245,6 +247,66 @@ class TestPipeline:
         ck2.save(state, 2)
         restored, _ = ck2.restore_latest(state)
         assert states_equal(state, restored)
+
+    @pytest.mark.parametrize("put_fails", [False, True], ids=["puts_land", "put_raises"])
+    def test_wave_buffers_reused_only_after_their_puts(self, monkeypatch, put_fails):
+        """Puts that sleep keep two waves in flight while the next one is
+        encoded: every stored blob must still be the chunk a fresh
+        per-group encode makes (no put reads a later wave's bytes), no
+        wave buffer is allocated after the first save, and the free list
+        never holds more than three.  With a put that raises in the middle
+        save, every buffer still comes back."""
+        from repro import telemetry
+        from repro.checkpoint import manager
+        from repro.ec import ECCodec
+
+        cfg, state = tiny_state()
+        fabric = small_fabric()
+        ck = DRexCheckpointer(fabric, "drex_sc", CheckpointPolicy(
+            item_mb=0.25, pipeline_workers=2, encode_wave_groups=2))
+        real_put, calls, held = StorageFabric.put, [0], []
+
+        def slow_put(self, node, key, blob):
+            time.sleep(0.002)
+            calls[0] += 1
+            if put_fails and key.startswith("ck102_") and calls[0] % 40 == 0:
+                raise IOError("planted put failure")
+            return real_put(self, node, key, blob)
+
+        real_give = manager._WaveBuffers.give
+
+        def give(bufs, buf):
+            real_give(bufs, buf)
+            held.append(len(bufs._free))
+
+        monkeypatch.setattr(StorageFabric, "put", slow_put)
+        monkeypatch.setattr(manager._WaveBuffers, "give", give)
+        telemetry.reset(prefilter_counters=False, matrix_caches=False, compile_census=False)
+        leaves = [np.asarray(x).tobytes() for x in jax.tree.leaves(state)]
+        max_bytes = int(ck.policy.item_mb * 1e6)
+        for step in (101, 102, 103):
+            if put_fails and step == 102:
+                with pytest.raises(IOError, match="planted"):
+                    ck.save(state, step)
+                assert len(ck._wave_bufs._free) == 3  # all came back
+                continue
+            manifest = ck.save(state, step)
+            n_groups = 0
+            for raw, meta in zip(leaves, manifest["leaves"]):
+                for part, g in enumerate(meta["groups"]):
+                    payload = raw[part * max_bytes : (part + 1) * max_bytes]
+                    want = ECCodec(g["k"], g["p"]).encode(manager._pad_to_bucket(payload))
+                    for row, node in enumerate(g["node_ids"]):
+                        blob = fabric.get(node, f"{g['key']}_r{row}")
+                        np.testing.assert_array_equal(np.frombuffer(blob, np.uint8), want[row])
+                    n_groups += 1
+            assert n_groups > 6  # four or more waves: the buffers cycle
+        per = {r["request"]: r["spans"] for r in telemetry.span_stats()["requests"]}
+        assert per[101]["codec.wave_alloc"]["count"] >= 3
+        for step in (102, 103):
+            assert "codec.wave_alloc" not in per[step]
+        assert per[103]["codec.wave_buffer"]["count"] > 3  # the buffers cycled
+        assert held and max(held) <= 3
 
 
 class TestKernelVsRefCodecs:

@@ -31,18 +31,48 @@ class TestEncodeMany:
         p=st.integers(1, 4),
         lengths=st.lists(st.integers(0, 9000), min_size=1, max_size=8),
         seed=st.integers(0, 2**31),
+        spare=st.sampled_from([None, 0, 100]),
     )
     @settings(max_examples=25, deadline=None)
-    def test_property_matches_per_item(self, k, p, lengths, seed):
+    def test_property_matches_per_item(self, k, p, lengths, seed, spare):
         """One cohort launch is byte-identical to per-item encodes across
-        mixed lengths, tail (non-bucket-aligned) widths and empties."""
+        mixed lengths, tail (non-bucket-aligned) widths and empties, in a
+        fresh wave buffer (``spare`` None) or a caller's stale one with
+        ``spare`` bytes to spare."""
         payloads = _payloads(lengths, seed)
         codec = ECCodec(k, p)
-        got = codec.encode_many(payloads)
+        out = None
+        if spare is not None:
+            out = np.full(codec.wave_nbytes([len(pl) for pl in payloads]) + spare, 0xA5, np.uint8)
+        got = codec.encode_many(payloads, out=out)
         want = [codec.encode(pl) for pl in payloads]
         assert len(got) == len(want)
         for g, w in zip(got, want):
             np.testing.assert_array_equal(g, w)
+
+    @pytest.mark.parametrize("given_out", [False, True])
+    def test_chunks_are_views_of_one_wave_buffer(self, given_out):
+        codec = ECCodec(4, 2)
+        payloads = _payloads([5000, 0, 4097, 12], seed=7)
+        nbytes = codec.wave_nbytes([len(pl) for pl in payloads])
+        out = np.empty(nbytes, np.uint8) if given_out else None
+        got = codec.encode_many(payloads, out=out)
+        base = out if given_out else got[0].base
+        assert base.shape == (nbytes,)
+        for chunks, pl in zip(got, payloads):
+            assert chunks.shape == (6, codec.chunk_len(len(pl)))
+            assert chunks.base is base
+            assert all(row.flags.c_contiguous for row in chunks)
+
+    def test_too_small_wave_buffer_raises(self):
+        codec = ECCodec(4, 2)
+        payloads = _payloads([5000, 300], seed=8)
+        nbytes = codec.wave_nbytes([5000, 300])
+        assert nbytes == 6 * (1250 + 75)
+        for bad in (np.empty(nbytes - 1, np.uint8), np.empty(nbytes, np.int8),
+                    np.empty((2, nbytes), np.uint8), np.empty(2 * nbytes, np.uint8)[::2]):
+            with pytest.raises(ValueError, match="wave buffer"):
+                codec.encode_many(payloads, out=bad)
 
     def test_mixed_kp_batch_matches_per_item(self):
         specs = [(3, 2), (6, 3), (3, 2), (4, 2), (6, 3)]

@@ -156,8 +156,10 @@ class TestSpan:
 # -- the spans of a checkpoint save ----------------------------------------------
 
 SAVE_SPANS = {"ckpt.save", "ckpt.d2h", "ckpt.split", "ckpt.place", "ckpt.encode",
-              "codec.stage", "codec.concat", "codec.h2d", "codec.wait", "codec.d2h",
-              "codec.assemble", "ckpt.put", "ckpt.put_wait"}
+              "codec.wave_buffer", "codec.wave_alloc", "codec.stage", "codec.h2d",
+              "codec.wait", "codec.d2h", "codec.assemble", "ckpt.put", "ckpt.put_wait"}
+#: a save shaped like an earlier one reuses that one's wave buffers
+WARM_SAVE_SPANS = SAVE_SPANS - {"codec.wave_alloc"}
 SAVE_CHILDREN = {"ckpt.d2h", "ckpt.split", "ckpt.place", "ckpt.encode", "ckpt.put_wait"}
 
 
@@ -168,10 +170,11 @@ def _bucket(n: int) -> int:
     return b
 
 
-def _reckoned_bytes(manifest) -> dict[str, int]:
+def _reckoned_bytes(manifest, spans) -> dict[str, int]:
     """Bytes each span should write, from the manifest alone: leaf bytes,
-    group sizes, (K, P) and the power-of-two bucket."""
-    want = dict.fromkeys(SAVE_SPANS, 0)
+    group sizes, (K, P) and the power-of-two bucket.  The wave buffer's
+    acquire and allocation write nothing (``np.empty``)."""
+    want = dict.fromkeys(spans, 0)
     for meta in manifest["leaves"]:
         leaf = int(np.prod(meta["shape"])) * np.dtype(meta["dtype"]).itemsize
         want["ckpt.save"] += leaf
@@ -185,9 +188,8 @@ def _reckoned_bytes(manifest) -> dict[str, int]:
             if b > n:
                 want["ckpt.split"] += 2 * b - n  # zero filler, then padded payload
             clen = -(-b // k)
-            for name, rows in (("codec.stage", k), ("codec.concat", k), ("codec.h2d", k),
-                               ("codec.d2h", p), ("codec.assemble", k + p),
-                               ("ckpt.put", k + p)):
+            for name, rows in (("codec.stage", k), ("codec.h2d", k), ("codec.d2h", p),
+                               ("codec.assemble", p), ("ckpt.put", k + p)):
                 want[name] += rows * clen
     return want
 
@@ -208,13 +210,17 @@ def test_save_spans_fire_cover_and_count_bytes(records, workers):
     ck = DRexCheckpointer(fabric, "drex_sc", CheckpointPolicy(
         item_mb=0.25, pipeline_workers=workers, encode_wave_groups=2))
     state = _state()
-    ck.save(state, 0)  # compiles
+    first = ck.save(state, 0)  # compiles, allocates the wave buffers
+    assert {r.name for r in records} == SAVE_SPANS
+    assert telemetry.span_stats()["totals"]["codec.wave_alloc"]["nbytes"] == 0
+    assert {n: t["nbytes"] for n, t in telemetry.span_stats()["totals"].items()} == \
+        _reckoned_bytes(first, SAVE_SPANS)
     encode_s0 = ck.stats["encode_s"]
     del records[:]
     telemetry.reset(prefilter_counters=False, matrix_caches=False, compile_census=False)
     manifest = ck.save(state, 1)
 
-    assert {r.name for r in records} == SAVE_SPANS
+    assert {r.name for r in records} == WARM_SAVE_SPANS
     assert {r.request for r in records} == {1}
     (save,) = [r for r in records if r.name == "ckpt.save"]
     children = [r for r in records if r.parent_id == save.span_id]
@@ -224,7 +230,7 @@ def test_save_spans_fire_cover_and_count_bytes(records, workers):
     assert covered >= 0.9 * (save.end_ns - save.start_ns)
 
     got = {name: t["nbytes"] for name, t in telemetry.span_stats()["totals"].items()}
-    assert got == _reckoned_bytes(manifest)
+    assert got == _reckoned_bytes(manifest, WARM_SAVE_SPANS)
     assert got["ckpt.save"] == sum(x.nbytes for x in jax.tree.leaves(state))
     # the encode stage's seconds are the ckpt.encode spans'
     assert ck.stats["encode_s"] - encode_s0 == pytest.approx(
