@@ -25,6 +25,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from .. import telemetry
 from . import constraints as constraints_mod
 from . import greedy_kernel, lb_kernel, prefilter, sc_kernel
 from .incremental import FreeOrderTracker, SaturationTracker
@@ -1094,12 +1095,62 @@ class DRexSC(Scheduler):
         ctx,
         constraints=None,
     ) -> list[Decision]:
+        """Spans ``place.order`` (candidate order, pre-filter slice and
+        per-item inputs), ``place.kernel`` (the kernel's launch and wait)
+        and ``place.select`` (decisions from its winners); counters
+        ``place.rows`` (items scored) and ``place.distinct`` (items whose
+        per-item inputs no earlier item of the launch shares)."""
+        with telemetry.span("place.order"):
+            inputs = self._kernel_inputs(items, smins, cluster, ctx, constraints)
+        if inputs is None:
+            return [Decision(None, 0, "fewer than 2 live nodes") for _ in items]
+        by_free_k, L, args = inputs
+        with telemetry.span("place.kernel"):
+            ok, s, n, k, p = sc_kernel.score_windows_batch(*args)
+        per_item = np.column_stack(args[:6])  # probs, size, target, smin, fbase, ssat
+        telemetry.count("place.rows", len(items))
+        telemetry.count("place.distinct", len({row.tobytes() for row in per_item}))
+        with telemetry.span("place.select"):
+            considered = min(L * (L - 1) // 2, self.MAX_MAPPINGS)
+            decisions = []
+            for row in range(len(items)):
+                if not ok[row]:
+                    decisions.append(
+                        Decision(
+                            None, considered, "no mapping satisfies reliability+capacity"
+                        )
+                    )
+                    continue
+                s_r, n_r = int(s[row]), int(n[row])
+                decisions.append(
+                    Decision(
+                        Placement(
+                            k=int(k[row]),
+                            p=int(p[row]),
+                            node_ids=tuple(int(x) for x in by_free_k[s_r : s_r + n_r]),
+                        ),
+                        considered,
+                        "",
+                    )
+                )
+        return decisions
+
+    def _kernel_inputs(
+        self,
+        items: list[DataItem],
+        smins: Sequence[float],
+        cluster: ClusterView,
+        ctx,
+        constraints=None,
+    ):
+        """``(by_free_k, L, args of score_windows_batch)``, or ``None``
+        with fewer than 2 candidate nodes."""
         by_free = self._apply_constraints(
             self._by_free(cluster), cluster, constraints
         )  # line 1
         L = len(by_free)
         if L < 2:
-            return [Decision(None, 0, "fewer than 2 live nodes") for _ in items]
+            return None
         live = cluster.live_ids()
         # Saturation terms stay cluster-global under constraints: the
         # 1/L anchor and the baseline sum describe the repository, not
@@ -1156,7 +1207,7 @@ class DRexSC(Scheduler):
                 base_cache[smin] = got
             fbase[row], ssat[row] = got
         tm = self.time_model
-        ok, s, n, k, p = sc_kernel.score_windows_batch(
+        args = (
             probs_mat,
             np.array([it.size_mb for it in items], dtype=np.float64),
             np.array([it.reliability_target for it in items], dtype=np.float64),
@@ -1170,31 +1221,9 @@ class DRexSC(Scheduler):
             cap[by_free_k],
             self.MAX_MAPPINGS,
             (tm.e0, tm.e_byte, tm.e_mult, tm.d0, tm.d_byte, tm.d_mult),
-            n_live=L_live,
+            L_live,
         )
-        considered = min(L * (L - 1) // 2, self.MAX_MAPPINGS)
-        decisions = []
-        for row in range(len(items)):
-            if not ok[row]:
-                decisions.append(
-                    Decision(
-                        None, considered, "no mapping satisfies reliability+capacity"
-                    )
-                )
-                continue
-            s_r, n_r = int(s[row]), int(n[row])
-            decisions.append(
-                Decision(
-                    Placement(
-                        k=int(k[row]),
-                        p=int(p[row]),
-                        node_ids=tuple(int(x) for x in by_free_k[s_r : s_r + n_r]),
-                    ),
-                    considered,
-                    "",
-                )
-            )
-        return decisions
+        return by_free_k, L, args
 
     # -- scalar oracle ------------------------------------------------------
 

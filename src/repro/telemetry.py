@@ -27,7 +27,10 @@ adds its count, seconds, self seconds (its duration less that of the
 spans nested in it on the same thread) and the bytes it wrote into new
 buffers to process-wide totals, and to the totals of its request (a
 checkpoint save's step) for the last :data:`REQUESTS_KEPT` requests.
-The totals are always on and cost a few microseconds a span.  Full
+The totals are always on and cost a few microseconds a span.
+Counters (:func:`count`) add a number under a name to the same
+process-wide and per-request totals, where the request is the one of
+the span open on the calling thread (or given explicitly).  Full
 span records (:class:`SpanRecord`) exist only while a listener is
 registered (:func:`add_span_listener`), and go to the listener alone.
 Each span also opens a ``jax.profiler.TraceAnnotation`` of its name, so
@@ -52,6 +55,7 @@ __all__ = [
     "reset",
     "span",
     "span_stats",
+    "count",
     "SpanRecord",
     "add_span_listener",
     "remove_span_listener",
@@ -102,9 +106,23 @@ _span_ids = itertools.count(1)
 _span_stack = threading.local()
 #: name -> [count, ns, self ns, bytes], over the whole process.
 _span_totals: dict[str, list[int]] = {}
-#: request -> {name -> [count, ns, self ns, bytes]}, oldest request first.
+#: name -> summed count, over the whole process.
+_counter_totals: dict[str, int] = {}
+#: request -> ({span name -> [count, ns, self ns, bytes]}, {counter name ->
+#: summed count}), oldest request first.
 _request_totals: collections.OrderedDict = collections.OrderedDict()
 _span_listeners: list[Callable[[SpanRecord], None]] = []
+
+
+def _request(request: Hashable) -> tuple[dict, dict]:
+    """The request's (spans, counters) totals, made if new (oldest dropped
+    beyond :data:`REQUESTS_KEPT`); call under ``_span_lock``."""
+    per = _request_totals.get(request)
+    if per is None:
+        per = _request_totals[request] = ({}, {})
+        while len(_request_totals) > REQUESTS_KEPT:
+            _request_totals.popitem(last=False)
+    return per
 
 
 def _add(totals: dict, name: str, dur_ns: int, self_ns: int, nbytes: int) -> None:
@@ -143,12 +161,7 @@ def span(name: str, nbytes: int = 0, *, request: Optional[Hashable] = None) -> I
         with _span_lock:
             _add(_span_totals, name, dur, dur - sp.child_ns, sp.nbytes)
             if request is not None:
-                per = _request_totals.get(request)
-                if per is None:
-                    per = _request_totals[request] = {}
-                    while len(_request_totals) > REQUESTS_KEPT:
-                        _request_totals.popitem(last=False)
-                _add(per, name, dur, dur - sp.child_ns, sp.nbytes)
+                _add(_request(request)[0], name, dur, dur - sp.child_ns, sp.nbytes)
             listeners = tuple(_span_listeners)
         if listeners:
             rec = SpanRecord(name, sp.start_ns, sp.end_ns, sp.span_id,
@@ -156,6 +169,20 @@ def span(name: str, nbytes: int = 0, *, request: Optional[Hashable] = None) -> I
                              threading.get_ident(), request, sp.nbytes)
             for fn in listeners:
                 fn(rec)
+
+
+def count(name: str, n: int = 1, *, request: Optional[Hashable] = None) -> None:
+    """Add ``n`` to counter ``name``, process-wide and for ``request``
+    (by default the request of the span open on this thread, if any)."""
+    if request is None:
+        stack = getattr(_span_stack, "open", None)
+        if stack:
+            request = stack[-1].request
+    with _span_lock:
+        _counter_totals[name] = _counter_totals.get(name, 0) + n
+        if request is not None:
+            counters = _request(request)[1]
+            counters[name] = counters.get(name, 0) + n
 
 
 def add_span_listener(fn: Callable[[SpanRecord], None]) -> None:
@@ -178,14 +205,16 @@ def _span_dict(totals: dict) -> dict[str, dict[str, Any]]:
 
 
 def span_stats() -> dict[str, Any]:
-    """Span totals: ``{"totals": {name: {count, seconds, self_seconds,
-    nbytes}}, "requests": [{"request": r, "spans": {name: ...}}, ...]}``,
+    """Span and counter totals: ``{"totals": {name: {count, seconds,
+    self_seconds, nbytes}}, "counters": {name: n}, "requests":
+    [{"request": r, "spans": {name: ...}, "counters": {name: n}}, ...]}``,
     the requests oldest first."""
     with _span_lock:
         return {
             "totals": _span_dict(_span_totals),
-            "requests": [{"request": r, "spans": _span_dict(per)}
-                         for r, per in _request_totals.items()],
+            "counters": dict(_counter_totals),
+            "requests": [{"request": r, "spans": _span_dict(spans), "counters": dict(counters)}
+                         for r, (spans, counters) in _request_totals.items()],
         }
 
 
@@ -210,7 +239,8 @@ class TelemetrySnapshot:
     #: persistent XLA compilation-cache state —
     #: ``repro.core.jitcache.status()``.
     jit_cache: Optional[dict[str, Any]] = None
-    #: host span totals, process-wide and per request — :func:`span_stats`.
+    #: host span and counter totals, process-wide and per request —
+    #: :func:`span_stats`.
     spans: Optional[dict[str, Any]] = None
 
     def as_dict(self) -> dict[str, Any]:
@@ -247,7 +277,8 @@ def reset(
     Engine counters are per-instance and unaffected — construct a fresh
     engine instead.  Resetting the compile census clears the bucketer's
     issued-shape census, not the jit caches themselves.  Resetting the
-    spans clears their totals; listeners stay registered.
+    spans clears their totals and the counters' (:func:`count`);
+    listeners stay registered.
     """
     from repro.core import prefilter, shapes
     from repro.kernels import ops as kops
@@ -261,4 +292,5 @@ def reset(
     if spans:
         with _span_lock:
             _span_totals.clear()
+            _counter_totals.clear()
             _request_totals.clear()

@@ -1,7 +1,8 @@
 """Host spans of ``repro.telemetry``: nesting and self time, request ids,
-exact totals under threads, reset, listeners, the snapshot schema, and
-the spans of a checkpoint save (every stage fires, the save's own thread
-is covered, and the bytes match what the manifest says was copied)."""
+exact totals under threads, reset, listeners, counters per request, the
+snapshot schema, and the spans of a checkpoint save (every stage fires,
+the save's own thread is covered, and the bytes match what the manifest
+says was copied)."""
 
 import sys
 import threading
@@ -125,7 +126,7 @@ class TestSpan:
         telemetry.reset(spans=False)
         assert telemetry.span_stats()["totals"]
         telemetry.reset(prefilter_counters=False, matrix_caches=False, compile_census=False)
-        assert telemetry.span_stats() == {"totals": {}, "requests": []}
+        assert telemetry.span_stats() == {"totals": {}, "counters": {}, "requests": []}
 
     def test_listener_add_and_remove(self):
         got = []
@@ -142,6 +143,29 @@ class TestSpan:
         # the totals count every span, listener or not
         assert telemetry.span_stats()["totals"]["unheard"]["count"] == 2
 
+    def test_counters_nest_under_the_request_of_the_open_span(self):
+        telemetry.count("rows", 3)  # no span open: process-wide only
+        with telemetry.span("save", request="s1"):
+            with telemetry.span("inner"):
+                telemetry.count("rows", 2)
+                telemetry.count("rows")
+            telemetry.count("distinct", 4)
+        with telemetry.span("save", request="s2"):
+            telemetry.count("rows", 5)
+        telemetry.count("rows", 7, request="s1")
+        stats = telemetry.span_stats()
+        assert stats["counters"] == {"rows": 18, "distinct": 4}
+        per = {r["request"]: r for r in stats["requests"]}
+        assert per["s1"]["counters"] == {"rows": 10, "distinct": 4}
+        assert per["s2"]["counters"] == {"rows": 5}
+        assert set(per["s1"]["spans"]) == {"save", "inner"}
+        # a request that only counts is kept like one that only spans
+        telemetry.count("rows", 1, request="s3")
+        assert telemetry.span_stats()["requests"][-1] == \
+            {"request": "s3", "spans": {}, "counters": {"rows": 1}}
+        telemetry.reset(prefilter_counters=False, matrix_caches=False, compile_census=False)
+        assert telemetry.span_stats()["counters"] == {}
+
     def test_snapshot_carries_spans(self):
         with telemetry.span("snap", 4, request=1):
             pass
@@ -149,13 +173,15 @@ class TestSpan:
         assert snap.spans["totals"]["snap"] == {
             "count": 1, "seconds": snap.spans["totals"]["snap"]["seconds"],
             "self_seconds": snap.spans["totals"]["snap"]["seconds"], "nbytes": 4}
-        assert snap.spans["requests"] == [{"request": 1, "spans": snap.spans["totals"]}]
+        assert snap.spans["requests"] == [
+            {"request": 1, "spans": snap.spans["totals"], "counters": {}}]
         assert "spans" in snap.as_dict()
 
 
 # -- the spans of a checkpoint save ----------------------------------------------
 
 SAVE_SPANS = {"ckpt.save", "ckpt.d2h", "ckpt.split", "ckpt.place", "ckpt.encode",
+              "place.order", "place.kernel", "place.select",
               "codec.wave_buffer", "codec.wave_alloc", "codec.stage", "codec.h2d",
               "codec.wait", "codec.d2h", "codec.assemble", "ckpt.put", "ckpt.put_wait"}
 #: a save shaped like an earlier one reuses that one's wave buffers
@@ -235,6 +261,21 @@ def test_save_spans_fire_cover_and_count_bytes(records, workers):
     # the encode stage's seconds are the ckpt.encode spans'
     assert ck.stats["encode_s"] - encode_s0 == pytest.approx(
         sum(r.end_ns - r.start_ns for r in records if r.name == "ckpt.encode") / 1e9)
+
+
+def test_save_counts_placement_rows_under_its_request():
+    """The decision kernel's counters of a save land in the save's own
+    request, beside its spans: one row per group."""
+    fabric = StorageFabric(make_node_set("most_used", capacity_scale=1e-3))
+    ck = DRexCheckpointer(fabric, "drex_sc", CheckpointPolicy(item_mb=0.25))
+    manifest = ck.save(_state(), 5)
+    groups = sum(len(meta["groups"]) for meta in manifest["leaves"])
+    (req,) = telemetry.span_stats()["requests"]
+    assert req["request"] == 5
+    assert {"place.order", "place.kernel", "place.select"} <= set(req["spans"])
+    assert req["counters"]["place.rows"] == groups
+    assert 1 <= req["counters"]["place.distinct"] < groups
+    assert telemetry.span_stats()["counters"] == req["counters"]
 
 
 def test_spans_reach_a_host_trace(tmp_path):

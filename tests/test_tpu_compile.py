@@ -11,7 +11,8 @@ v5e keeps them ready to move back once that is settled.
 
 Shapes kept (each compiles within about 15 s on a CPU host):
 
-* Pallas Cauchy-RS bit-matmul at (K, P) = (6, 2), (10, 4), (128, 16)
+* Pallas Cauchy-RS bit-matmul at (K, P) = (6, 2), (10, 4), (69, 5) (the
+  widest stripe D-Rex SC picks on the 12,000-drive fleet), (128, 16)
   over a 128 KiB byte column;
 * D-Rex SC at the paper's 10-node pad (S, L) = (15, 16) and the largest
   exact-multiple rung (63, 64);
@@ -72,7 +73,7 @@ def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("k,p", [(6, 2), (10, 4), (128, 16)])
+@pytest.mark.parametrize("k,p", [(6, 2), (10, 4), (69, 5), (128, 16)])
 def test_pallas_coding_kernel(one_chip, k, p):
     compiled = jax.jit(gf_bitmatmul).lower(
         _spec(one_chip, (8 * p, 8 * k), jnp.float32),
