@@ -925,6 +925,22 @@ def saturation_score(projected_used_mb, capacity_mb, smin_mb, n_nodes: int = 10)
     return np.clip(inv_l * np.exp(math.log(max(2, n_nodes)) * u), 0.0, 1.0)
 
 
+def _distinct_rows(rows: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """``(first, inverse)`` over the rows of a 2-D array, keyed by their
+    bytes: ``first`` holds the index of each distinct row's first
+    occurrence, in order, and ``rows[first][inverse]`` equals ``rows``.
+    Bytes, not values: -0.0 and 0.0 stay two rows."""
+    index: dict[bytes, int] = {}
+    first: list[int] = []
+    inverse = np.empty(len(rows), dtype=np.intp)
+    for i, row in enumerate(rows):
+        j = index.setdefault(row.tobytes(), len(first))
+        if j == len(first):
+            first.append(i)
+        inverse[i] = j
+    return np.asarray(first, dtype=np.intp), inverse
+
+
 @register_scheduler(
     "drex_sc",
     adaptive=True,
@@ -1052,6 +1068,11 @@ class DRexSC(Scheduler):
         """Score ``items`` against the *current* cluster snapshot in one
         vmapped kernel call.
 
+        The call scores each distinct row of kernel inputs once (items
+        of one size, retention, target and smin anchor share a row) and
+        hands every item its row's winner; ``place.distinct`` counts the rows it
+        scored, ``place.rows`` the items (see :meth:`_place_kernel`).
+
         Pure: scheduler state (``smin_mb``) is not mutated — each item is
         scored with the running smallest-size anchor it would see under
         sequential ``place`` calls, and the consumer (the engine's
@@ -1095,22 +1116,32 @@ class DRexSC(Scheduler):
         ctx,
         constraints=None,
     ) -> list[Decision]:
-        """Spans ``place.order`` (candidate order, pre-filter slice and
-        per-item inputs), ``place.kernel`` (the kernel's launch and wait)
-        and ``place.select`` (decisions from its winners); counters
-        ``place.rows`` (items scored) and ``place.distinct`` (items whose
-        per-item inputs no earlier item of the launch shares)."""
+        """Score a launch's distinct rows once and fan the winners out.
+
+        A row is an item's per-item kernel inputs (failure probabilities,
+        size, target, smin, saturation baseline and system saturation);
+        rows equal byte for byte share one scoring, since the kernel is a
+        pure function of the row and the shared snapshot.  Spans
+        ``place.order`` (candidate order, pre-filter slice, per-item
+        inputs and their keying), ``place.kernel`` (the kernel's launch
+        and wait, on the distinct rows) and ``place.select`` (decisions
+        from the winners, fanned back to every item); counters
+        ``place.rows`` (items asked for) and ``place.distinct`` (rows the
+        kernel scored)."""
         with telemetry.span("place.order"):
             inputs = self._kernel_inputs(items, smins, cluster, ctx, constraints)
+            if inputs is not None:
+                by_free_k, L, args = inputs
+                first, inverse = _distinct_rows(np.column_stack(args[:6]))
+                args = tuple(a[first] for a in args[:6]) + args[6:]
         if inputs is None:
             return [Decision(None, 0, "fewer than 2 live nodes") for _ in items]
-        by_free_k, L, args = inputs
         with telemetry.span("place.kernel"):
-            ok, s, n, k, p = sc_kernel.score_windows_batch(*args)
-        per_item = np.column_stack(args[:6])  # probs, size, target, smin, fbase, ssat
+            winners = sc_kernel.score_windows_batch(*args)
         telemetry.count("place.rows", len(items))
-        telemetry.count("place.distinct", len({row.tobytes() for row in per_item}))
+        telemetry.count("place.distinct", len(first))
         with telemetry.span("place.select"):
+            ok, s, n, k, p = (a[inverse] for a in winners)
             considered = min(L * (L - 1) // 2, self.MAX_MAPPINGS)
             decisions = []
             for row in range(len(items)):

@@ -232,3 +232,114 @@ class TestKernelOracleEquivalence:
         got = [d.placement for d in sched_batch.place_batch(items, cluster)]
         want = [sched_seq.place(it, cluster).placement for it in items]
         assert got == want
+
+
+class TestDistinctRows:
+    """A launch scores each distinct row of kernel inputs once and fans
+    the winners back to every item, with decisions unchanged."""
+
+    #: a checkpoint save's four bucket sizes (4 KiB to 32 MiB) x 64, on
+    #: nodes small enough that size moves the decision and 2 GB fits none
+    SIZES = (0.25, 64.0, 512.0, 2048.0)
+
+    @pytest.fixture
+    def launched(self, monkeypatch):
+        from repro.core import sc_kernel
+
+        calls = []
+        score = sc_kernel.score_windows_batch
+
+        def spy(*args):
+            calls.append(args)
+            return score(*args)
+
+        monkeypatch.setattr(sc_kernel, "score_windows_batch", spy)
+        return calls
+
+    def _repeated(self):
+        """64 items over the four sizes, smallest first (one smin anchor
+        for every row), the rest in shuffled order."""
+        sizes = list(self.SIZES * 16)
+        rest = np.random.default_rng(0).permutation(sizes[1:]).tolist()
+        return self._items(sizes[:1] + rest)
+
+    def _items(self, sizes):
+        return [DataItem(i, s, float(i), 365.0, 0.99999) for i, s in enumerate(sizes)]
+
+    def _engines(self):
+        nodes = make_node_set("most_used", 1e-5)
+        return (
+            PlacementEngine(nodes, scalar_scheduler(), auto_commit=False),
+            PlacementEngine(nodes, forced_kernel_scheduler(), auto_commit=False),
+        )
+
+    def test_repeated_rows_match_per_row_scoring_and_oracle(self, launched):
+        items = self._repeated()
+        oracle, batched = self._engines()
+        want = [oracle.place(it) for it in items]
+        got = batched.place_many(items)
+        sched = forced_kernel_scheduler()
+        sched.smin_mb = min(self.SIZES)
+        per_row = [sched.place_batch([it], batched.cluster)[0] for it in items]
+        for w, g, r in zip(want, got, per_row):
+            assert w.placement == g.placement == r.placement
+            assert w.candidates_considered == g.candidates_considered == r.candidates_considered
+            assert w.reason == g.reason == r.reason
+        assert len({g.placement for g in got}) == 3 and not all(g.ok for g in got)
+
+    def test_repeated_rows_launch_only_the_distinct_rows(self, launched):
+        items = self._repeated()
+        self._engines()[1].place_many(items)
+        assert launched
+        for args in launched:
+            rows = np.column_stack(args[:6])
+            assert len(rows) == len({r.tobytes() for r in rows}) == len(self.SIZES)
+        assert sum(len(args[0]) for args in launched) == len(self.SIZES) * len(launched)
+
+    def test_all_distinct_rows_are_scored_as_before(self, launched):
+        items = self._items([1.0 + i for i in range(24)])
+        sched = forced_kernel_scheduler()
+        cluster = ClusterView.from_nodes(make_node_set("most_used", 0.001))
+        got = sched.place_batch(items, cluster)
+        (args,) = launched
+        smins = [1.0] * len(items)
+        _, _, want_args = sched._kernel_inputs(items, smins, cluster, None)
+        assert len(args) == len(want_args)
+        for a, b in zip(args, want_args):
+            if isinstance(b, np.ndarray):
+                assert a.tobytes() == b.tobytes() and a.shape == b.shape
+            else:
+                assert a == b
+        oracle = scalar_scheduler()
+        assert [d.placement for d in got] == [oracle.place(it, cluster).placement for it in items]
+
+    def test_rows_differing_only_in_smin_stay_apart(self, launched):
+        # sizes 8, 8, 1, 8: the running smin anchor moves at the third
+        # item, so the fourth row repeats the first's size under a new smin
+        items = self._items([8.0, 8.0, 1.0, 8.0])
+        sched = forced_kernel_scheduler()
+        cluster = ClusterView.from_nodes(make_node_set("most_used", 0.001))
+        got = sched.place_batch(items, cluster)
+        (args,) = launched
+        np.testing.assert_array_equal(args[1], [8.0, 1.0, 8.0])
+        np.testing.assert_array_equal(args[3], [8.0, 1.0, 1.0])
+        oracle = scalar_scheduler()
+        assert [d.placement for d in got] == [oracle.place(it, cluster).placement for it in items]
+
+    @pytest.mark.parametrize(
+        "rows,first,inverse",
+        [
+            ([[0.0, 1.0], [-0.0, 1.0], [0.0, 1.0]], [0, 1], [0, 1, 0]),
+            ([[0.5, 2.0, 1.0], [0.5, 2.0, 0.25], [0.5, 2.0, 1.0]], [0, 1], [0, 1, 0]),
+            ([[1.0], [2.0], [3.0]], [0, 1, 2], [0, 1, 2]),
+        ],
+        ids=["sign_of_zero", "smin_only", "all_distinct"],
+    )
+    def test_distinct_rows_key_by_bytes(self, rows, first, inverse):
+        from repro.core.algorithms import _distinct_rows
+
+        rows = np.asarray(rows, dtype=np.float64)
+        got_first, got_inverse = _distinct_rows(rows)
+        assert got_first.tolist() == first
+        assert got_inverse.tolist() == inverse
+        np.testing.assert_array_equal(rows[got_first][got_inverse], rows)

@@ -263,9 +263,20 @@ def test_save_spans_fire_cover_and_count_bytes(records, workers):
         sum(r.end_ns - r.start_ns for r in records if r.name == "ckpt.encode") / 1e9)
 
 
-def test_save_counts_placement_rows_under_its_request():
+def test_save_counts_placement_rows_under_its_request(monkeypatch):
     """The decision kernel's counters of a save land in the save's own
-    request, beside its spans: one row per group."""
+    request, beside its spans: one row per group asked for, and the rows
+    the kernel was launched with, which repeat no row of their launch."""
+    from repro.core import sc_kernel
+
+    launched = []
+    score = sc_kernel.score_windows_batch
+
+    def spy(probs_mat, *rest):
+        launched.append(len(probs_mat))
+        return score(probs_mat, *rest)
+
+    monkeypatch.setattr(sc_kernel, "score_windows_batch", spy)
     fabric = StorageFabric(make_node_set("most_used", capacity_scale=1e-3))
     ck = DRexCheckpointer(fabric, "drex_sc", CheckpointPolicy(item_mb=0.25))
     manifest = ck.save(_state(), 5)
@@ -274,6 +285,7 @@ def test_save_counts_placement_rows_under_its_request():
     assert req["request"] == 5
     assert {"place.order", "place.kernel", "place.select"} <= set(req["spans"])
     assert req["counters"]["place.rows"] == groups
+    assert req["counters"]["place.distinct"] == sum(launched)
     assert 1 <= req["counters"]["place.distinct"] < groups
     assert telemetry.span_stats()["counters"] == req["counters"]
 
