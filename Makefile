@@ -24,7 +24,7 @@ test:
 	$(PYTHON) -m pytest -x -q
 
 ## Quick-feedback lane (< 30 s): everything except the @pytest.mark.slow
-## model/e2e sweeps — covers the reliability kernel, schedulers, engine,
+## checkpoint/e2e/simulator sweeps — covers the reliability kernel, schedulers, engine,
 ## SC-kernel equivalence, invariant suite, simulator and traces.
 test-fast:
 	$(PYTHON) -m pytest -x -q -m "not slow"

@@ -2,7 +2,7 @@
 
 Prints ``name,us_per_call,derived`` CSV per the harness contract, and
 writes per-figure JSON (stamped with ``meta``: schema version, git SHA,
-smoke flag) into results/benchmarks/ for EXPERIMENTS.md.
+smoke flag) into results/benchmarks/.
 
     PYTHONPATH=src python -m benchmarks.run [--only fig5,fig12] [--smoke]
         [--out DIR] [--check-against BASELINE_DIR]

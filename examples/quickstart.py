@@ -1,44 +1,60 @@
-"""Quickstart: end-to-end training driver (deliverable (b), example 1).
+"""Quickstart: D-Rex EC-protected checkpoints of a training job's state.
 
-Trains a ~100M-parameter dense LM for a few hundred steps on synthetic
-data through the full production stack: config -> sharded train step ->
-AdamW(f32 master) -> D-Rex EC-protected checkpoints on a heterogeneous
-storage fabric -> kill/restore drill at the end.
+Builds a training job's state as a plain pytree of jax arrays (fp32
+weights, Adam m and v, a step counter), runs a stand-in update loop that
+saves it through `DRexCheckpointer` onto the paper's Most Used node set,
+then kills two storage nodes and restores the newest checkpoint
+bit-exact.
 
-CPU-friendly by default (a reduced ~8M model, 200 steps); pass --full
-for the 100M-parameter configuration.
+CPU-friendly by default (about 3M parameters); pass --full for about 60M.
 
-    PYTHONPATH=src python examples/quickstart.py [--full] [--steps N]
+    python examples/quickstart.py [--full] [--steps N]
 """
 
 import argparse
 import sys
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 
 sys.path.insert(0, "src")
 
 from repro.checkpoint import CheckpointPolicy, DRexCheckpointer, StorageFabric
-from repro.data import DataConfig
-from repro.launch.mesh import make_local_mesh
-from repro.models.config import ModelConfig
-from repro.optim import AdamWConfig
 from repro.storage import make_node_set
-from repro.train import Trainer, TrainerConfig, init_train_state
 
 
-def model_config(full: bool) -> ModelConfig:
-    if full:  # ~103M params
-        return ModelConfig(
-            name="quickstart-100m", family="dense", n_layers=12, d_model=768,
-            n_heads=12, n_kv_heads=4, d_ff=2048, vocab_size=32_000,
-            activation="silu",
-        )
-    return ModelConfig(  # ~8M params: same family, laptop-scale
-        name="quickstart-8m", family="dense", n_layers=4, d_model=256,
-        n_heads=8, n_kv_heads=4, d_ff=1024, vocab_size=4096,
-        activation="silu",
+def make_state(full: bool) -> dict:
+    vocab, d_model, d_ff, n_layers = (32_000, 768, 2048, 12) if full else (4096, 256, 1024, 4)
+    keys = iter(jax.random.split(jax.random.PRNGKey(0), 2 * n_layers + 1))
+
+    def normal(*shape):
+        return 0.02 * jax.random.normal(next(keys), shape, jnp.float32)
+
+    params = {
+        "embed": normal(vocab, d_model),
+        "layers": [
+            {"w_in": normal(d_model, d_ff), "w_out": normal(d_ff, d_model)}
+            for _ in range(n_layers)
+        ],
+    }
+    zeros = lambda: jax.tree.map(jnp.zeros_like, params)
+    return {"params": params, "opt": {"m": zeros(), "v": zeros(), "step": jnp.int32(0)}}
+
+
+@jax.jit
+def update(state: dict, key) -> dict:
+    """A stand-in training step: one Adam update from random gradients."""
+    params, opt = state["params"], state["opt"]
+    leaves, treedef = jax.tree.flatten(params)
+    keys = jax.random.split(key, len(leaves))
+    grads = jax.tree.unflatten(
+        treedef, [1e-3 * jax.random.normal(k, x.shape) for k, x in zip(keys, leaves)]
     )
+    m = jax.tree.map(lambda m, g: 0.9 * m + 0.1 * g, opt["m"], grads)
+    v = jax.tree.map(lambda v, g: 0.999 * v + 0.001 * g * g, opt["v"], grads)
+    params = jax.tree.map(lambda p, m, v: p - 1e-3 * m / (jnp.sqrt(v) + 1e-8), params, m, v)
+    return {"params": params, "opt": {"m": m, "v": v, "step": opt["step"] + 1}}
 
 
 def main() -> None:
@@ -47,8 +63,9 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=200)
     args = ap.parse_args()
 
-    cfg = model_config(args.full)
-    print(f"model: {cfg.name} ({cfg.n_params()/1e6:.1f}M params), "
+    state = make_state(args.full)
+    n_params = sum(x.size for x in jax.tree.leaves(state["params"]))
+    print(f"state: {n_params/1e6:.1f}M parameters + Adam moments, "
           f"devices: {jax.device_count()}")
 
     # D-Rex-protected checkpointing over the paper's Most Used node set.
@@ -61,37 +78,29 @@ def main() -> None:
         CheckpointPolicy(item_mb=8.0, reliability_target=0.99999,
                          retention_days=365.0),
     )
-    like = init_train_state(cfg, jax.random.PRNGKey(0))
-
-    class Adapter:
-        def save(self, st, step): ck.save(st, step)
-        def save_async(self, st, step): return ck.save_async(st, step)
-        def restore_latest(self, _): return ck.restore_latest(like)
-
     ckpt_every = min(50, max(10, args.steps // 4))
-    trainer = Trainer(
-        cfg,
-        AdamWConfig(lr=3e-3, warmup_steps=20),
-        TrainerConfig(steps=args.steps, log_every=10, ckpt_every=ckpt_every),
-        data_cfg=DataConfig(vocab_size=cfg.vocab_size, seq_len=256, global_batch=8),
-        mesh=make_local_mesh(1, 1),
-        checkpointer=Adapter(),
-    )
-    state = trainer.run()
+    key = jax.random.PRNGKey(1)
+    for step in range(1, args.steps + 1):
+        key, sub = jax.random.split(key)
+        state = update(state, sub)
+        if step % ckpt_every == 0:
+            ck.save(state, step)
+            saved = state
+            print(f"[ckpt] step {step:5d} saved")
 
     # Failure drill: lose two storage nodes, prove the checkpoint survives.
     print("\nfailure drill: killing storage nodes 0 and 3 ...")
     fabric.fail_node(0)
     fabric.fail_node(3)
-    restored, step = ck.restore_latest(like)
-    import numpy as np
+    restored, step = ck.restore_latest(state)
     ok = all(
         np.array_equal(np.asarray(a), np.asarray(b))
-        for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(restored))
-        if a is not None
+        for a, b in zip(jax.tree.leaves(saved), jax.tree.leaves(restored))
     )
     print(f"restored checkpoint from step {step} after 2/10 node failures: "
           f"bit-exact={ok}")
+    if not ok:
+        raise SystemExit("restore was not bit-exact")
     print(f"checkpoint storage overhead: "
           f"{ck.stats['bytes_stored']/max(ck.stats['bytes_raw'],1):.2f}x "
           f"(vs 3.0x for HDFS-style replication)")

@@ -24,7 +24,7 @@ then put), which benchmarks/fig13 uses as the upload baseline.
 
 The manifest is mesh-agnostic (leaf shapes/dtypes + tree structure), so
 restore composes with elastic rescale: `restore_latest` returns host
-arrays that `repro.train.step.reshard_state` lays out on any mesh.
+arrays that `jax.device_put` places on any mesh.
 """
 
 from __future__ import annotations
@@ -43,11 +43,13 @@ import numpy as np
 from repro import telemetry
 from repro.core import BatchContext, DataItem, Placement, PlacementEngine, Scheduler
 from repro.ec import ECCodec, plan_cohorts
-from repro.train.step import TrainState
 
 from .fabric import StorageFabric
 
 __all__ = ["CheckpointPolicy", "DRexCheckpointer"]
+
+#: any pytree of arrays, saved leaf by leaf.
+PyTree = Any
 
 
 @dataclasses.dataclass(frozen=True)
@@ -166,7 +168,7 @@ class DRexCheckpointer:
 
     # -- save -------------------------------------------------------------------
 
-    def save(self, state: TrainState, step: int) -> dict:
+    def save(self, state: PyTree, step: int) -> dict:
         """Encode→place→write one checkpoint through the batched pipeline.
 
         Placement decisions for all groups are made against the cluster
@@ -178,7 +180,7 @@ class DRexCheckpointer:
         with telemetry.span("ckpt.save", request=step) as sp:
             return self._save(state, step, sp)
 
-    def _save(self, state: TrainState, step: int, save_span) -> dict:
+    def _save(self, state: PyTree, step: int, save_span) -> dict:
         leaves, treedef = jax.tree.flatten(state)
         # The tree structure is reconstructed from a like-state at restore
         # (shapes/dtypes per leaf live in the manifest).
@@ -345,7 +347,7 @@ class DRexCheckpointer:
         with self._meta_lock:
             self.stats["bytes_stored"] += stored
 
-    def save_async(self, state: TrainState, step: int) -> Future:
+    def save_async(self, state: PyTree, step: int) -> Future:
         # device_get on the caller thread (consistent snapshot), encode+put
         # in the background — the async checkpointing pattern of [29, 30].
         leaves, _ = jax.tree.flatten(state)
@@ -361,16 +363,16 @@ class DRexCheckpointer:
 
     # -- restore ----------------------------------------------------------------
 
-    def restore_latest(self, like_state_or_cfg) -> Optional[tuple[TrainState, int]]:
+    def restore_latest(self, like_state: PyTree) -> Optional[tuple[PyTree, int]]:
         if not self._manifests:
             return None
         step = max(self._manifests)
-        return self.restore(step, like_state_or_cfg), step
+        return self.restore(step, like_state), step
 
-    def restore(self, step: int, like_state) -> TrainState:
+    def restore(self, step: int, like_state: PyTree) -> PyTree:
         """Rebuild the state pytree. ``like_state`` provides the tree
-        structure (a TrainState of matching config — e.g. freshly
-        initialized with `jax.eval_shape` or real arrays)."""
+        structure (a pytree of the saved structure — e.g. made with
+        `jax.eval_shape` or real arrays)."""
         manifest = self._manifests[step]
         leaves_meta = manifest["leaves"]
         like_leaves, treedef = jax.tree.flatten(like_state)

@@ -359,7 +359,7 @@ class ECTimeModel:
     T_encode = T_decode = 0); only the constant dispatch cost remains.
 
     Defaults are calibrated against our own GF(2^8) codec measurements
-    (benchmarks/fig1_encode_breakdown.py recalibrates; see EXPERIMENTS.md).
+    (benchmarks/fig1_encode_breakdown.py recalibrates).
     """
 
     e0: float = 1e-3
@@ -469,7 +469,7 @@ class Decision:
     """Result of one scheduling call."""
 
     placement: Optional[Placement]     # None => write failed
-    # Diagnostics for benchmarks / EXPERIMENTS.md:
+    # Diagnostics for benchmarks:
     candidates_considered: int = 0
     reason: str = ""
     #: dependency window for batched rescoring: the node ids whose free

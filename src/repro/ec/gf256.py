@@ -132,7 +132,8 @@ def decode_matrix(k: int, p: int, surviving_rows: np.ndarray) -> np.ndarray:
     return gf_mat_inv(g[rows, :])
 
 
-# -- bit-matrix construction (the TPU adaptation, DESIGN.md §4) -------------
+# -- bit-matrix construction ------------------------------------------------
+# The TPU adaptation: README "Batched erasure-coding data plane".
 
 _BIT_BASIS = np.array([1 << j for j in range(8)], dtype=np.uint8)
 

@@ -1,5 +1,5 @@
-"""Launchers: mesh construction, multi-pod dry-run, train/serve drivers."""
+"""Mesh construction."""
 
-from .mesh import make_local_mesh, make_production_mesh
+from .mesh import make_local_mesh
 
-__all__ = ["make_production_mesh", "make_local_mesh"]
+__all__ = ["make_local_mesh"]

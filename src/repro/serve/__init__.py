@@ -1,20 +1,5 @@
-"""Serving layer — two unrelated planes, namespaced apart:
-
-* :mod:`repro.serve.engine` — the batched **token**-serving engine
-  (prefill + decode loop over the model zoo);
-* :mod:`repro.serve.placement` — the streaming **placement** service
-  (admission queue, micro-batched ``place_many`` windows,
-  snapshot-epoch reads over a
-  :class:`~repro.core.engine.PlacementEngine`).
-
-``TokenServingEngine`` is the unambiguous name for the former;
-``ServingEngine`` remains as the original alias.
+"""Serving layer: :mod:`repro.serve.placement`, the streaming
+**placement** service (admission queue, micro-batched ``place_many``
+windows, snapshot-epoch reads over a
+:class:`~repro.core.engine.PlacementEngine`).
 """
-
-from .engine import ServeConfig, ServingEngine
-
-#: explicit name so call sites never conflate the token-serving engine
-#: with the storage placement service in :mod:`repro.serve.placement`.
-TokenServingEngine = ServingEngine
-
-__all__ = ["ServeConfig", "ServingEngine", "TokenServingEngine"]

@@ -1,21 +1,17 @@
 """EC-protected checkpointing tests: save/restore, node failures, repair,
-async path, GC, trainer integration, elastic restore."""
+async path, GC, a training client's restart, elastic restore."""
 
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec
 
+from _train_state import TrainingClient, states_equal, tiny_state
 from repro.checkpoint import CheckpointPolicy, DRexCheckpointer, StorageFabric
-from repro.configs import get_config
-from repro.core import create_scheduler
-from repro.data import DataConfig
 from repro.launch import make_local_mesh
-from repro.optim import AdamWConfig
 from repro.storage import make_node_set
-from repro.train import Trainer, TrainerConfig, init_train_state
 
 # checkpoint save/restore e2e: full lane only (deselect via -m "not slow").
 pytestmark = pytest.mark.slow
@@ -25,22 +21,10 @@ def small_fabric(scale=1e-5):
     return StorageFabric(make_node_set("most_used", capacity_scale=scale))
 
 
-def tiny_state(arch="yi_6b"):
-    cfg = get_config(arch, smoke=True)
-    return cfg, init_train_state(cfg, jax.random.PRNGKey(0))
-
-
-def states_equal(a, b) -> bool:
-    return all(
-        (x is None and y is None) or np.array_equal(np.asarray(x), np.asarray(y))
-        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b))
-    )
-
-
 class TestSaveRestore:
     @pytest.mark.parametrize("sched", ["drex_sc", "drex_lb", "greedy_min_storage", "greedy_least_used"])
     def test_roundtrip_all_schedulers(self, sched):
-        cfg, state = tiny_state()
+        state = tiny_state()
         ck = DRexCheckpointer(small_fabric(), sched, CheckpointPolicy(item_mb=0.25))
         ck.save(state, 1)
         restored, step = ck.restore_latest(state)
@@ -48,7 +32,7 @@ class TestSaveRestore:
         assert states_equal(state, restored)
 
     def test_restore_after_p_failures(self):
-        cfg, state = tiny_state()
+        state = tiny_state()
         fabric = small_fabric()
         ck = DRexCheckpointer(fabric, "drex_sc", CheckpointPolicy(item_mb=0.25, reliability_target=0.999))
         ck.save(state, 5)
@@ -57,7 +41,7 @@ class TestSaveRestore:
         assert states_equal(state, restored)
 
     def test_unrecoverable_when_too_many_failures(self):
-        cfg, state = tiny_state()
+        state = tiny_state()
         fabric = small_fabric()
         ck = DRexCheckpointer(fabric, "greedy_least_used", CheckpointPolicy(item_mb=0.25))
         ck.save(state, 5)
@@ -68,14 +52,14 @@ class TestSaveRestore:
 
     def test_storage_overhead_below_replication(self):
         """EC beats the 3x replication of HDFS-style systems (paper §1)."""
-        cfg, state = tiny_state()
+        state = tiny_state()
         ck = DRexCheckpointer(small_fabric(), "drex_sc", CheckpointPolicy(item_mb=0.25))
         ck.save(state, 1)
         overhead = ck.stats["bytes_stored"] / ck.stats["bytes_raw"]
         assert 1.0 < overhead < 2.0
 
     def test_async_save(self):
-        cfg, state = tiny_state()
+        state = tiny_state()
         ck = DRexCheckpointer(small_fabric(), "drex_lb", CheckpointPolicy(item_mb=0.25))
         fut = ck.save_async(state, 7)
         man = fut.result(timeout=120)
@@ -84,7 +68,7 @@ class TestSaveRestore:
         assert step == 7 and states_equal(state, restored)
 
     def test_gc_keeps_last_k(self):
-        cfg, state = tiny_state()
+        state = tiny_state()
         fabric = small_fabric()
         ck = DRexCheckpointer(fabric, "drex_lb", CheckpointPolicy(item_mb=0.25, keep_last=2))
         for s in (1, 2, 3):
@@ -98,7 +82,7 @@ class TestSaveRestore:
 
 class TestRepair:
     def test_repair_restores_reliability(self):
-        cfg, state = tiny_state()
+        state = tiny_state()
         fabric = small_fabric()
         ck = DRexCheckpointer(fabric, "drex_sc", CheckpointPolicy(item_mb=0.25, reliability_target=0.999))
         ck.save(state, 1)
@@ -111,7 +95,7 @@ class TestRepair:
         assert states_equal(state, restored)
 
     def test_repair_noop_when_healthy(self):
-        cfg, state = tiny_state()
+        state = tiny_state()
         ck = DRexCheckpointer(small_fabric(), "drex_sc", CheckpointPolicy(item_mb=0.25))
         ck.save(state, 1)
         assert ck.repair() == 0
@@ -123,7 +107,7 @@ class TestRepair:
         group on all 5 nodes; after one failure there are zero candidate
         nodes, so strict repair must raise (and must not partially
         re-map), while strict=False reports 0 chunks rebuilt."""
-        cfg, state = tiny_state()
+        state = tiny_state()
         fabric = StorageFabric(make_node_set("most_used", capacity_scale=1e-5)[:5])
         ck = DRexCheckpointer(
             fabric, "ec(3,2)",
@@ -154,7 +138,7 @@ class TestRepair:
         """Regression: repair must re-encode the bucket-padded payload —
         otherwise replacement chunks differ in shape from survivors and
         restore fails on groups whose size is not a power of two."""
-        cfg, state = tiny_state()
+        state = tiny_state()
         fabric = small_fabric()
         ck = DRexCheckpointer(fabric, "drex_lb", CheckpointPolicy(item_mb=0.25))
         ck.save(state, 1)
@@ -186,7 +170,7 @@ class TestPipeline:
 
     @pytest.mark.parametrize("wave", [1, 3, 16])
     def test_pipelined_matches_serial(self, wave):
-        cfg, state = tiny_state()
+        state = tiny_state()
         cks = {}
         for workers in (0, 2):
             ck = DRexCheckpointer(
@@ -203,7 +187,7 @@ class TestPipeline:
 
     def test_pipelined_respects_link_bandwidth_fabric(self):
         """Puts through a bandwidth-simulating fabric still land intact."""
-        cfg, state = tiny_state()
+        state = tiny_state()
         fabric = StorageFabric(
             make_node_set("most_used", capacity_scale=1e-5), link_mbps=2000.0
         )
@@ -217,7 +201,7 @@ class TestPipeline:
         """Two save_async calls in flight at once: both complete (drivers
         and I/O run on separate pools, so no cross-wait deadlock) and
         both checkpoints restore bit-exact."""
-        cfg, state = tiny_state()
+        state = tiny_state()
         ck = DRexCheckpointer(
             small_fabric(), "drex_lb",
             CheckpointPolicy(item_mb=0.25, keep_last=2, pipeline_workers=2,
@@ -234,7 +218,7 @@ class TestPipeline:
         """A fabric error inside a background put wave surfaces as the
         save's exception (no hang, no orphaned futures), and the
         checkpointer stays usable for a later save."""
-        cfg, state = tiny_state()
+        state = tiny_state()
         fabric = StorageFabric(make_node_set("most_used", capacity_scale=1e-9))
         ck = DRexCheckpointer(fabric, "drex_sc", CheckpointPolicy(
             item_mb=0.25, pipeline_workers=2, encode_wave_groups=2))
@@ -260,7 +244,7 @@ class TestPipeline:
         from repro.checkpoint import manager
         from repro.ec import ECCodec
 
-        cfg, state = tiny_state()
+        state = tiny_state()
         fabric = small_fabric()
         ck = DRexCheckpointer(fabric, "drex_sc", CheckpointPolicy(
             item_mb=0.25, pipeline_workers=2, encode_wave_groups=2))
@@ -311,7 +295,7 @@ class TestPipeline:
 
 class TestKernelVsRefCodecs:
     def test_checkpoint_identical_between_codecs(self):
-        cfg, state = tiny_state()
+        state = tiny_state()
         for use_kernel in (True, False):
             ck = DRexCheckpointer(
                 small_fabric(), "drex_lb",
@@ -324,48 +308,36 @@ class TestKernelVsRefCodecs:
 
 class TestTrainerIntegration:
     def test_checkpoint_restart_continues_training(self):
-        """Kill-and-restart: restored run picks up at the saved step."""
-        cfg = get_config("yi_6b", smoke=True)
-        fabric = small_fabric()
-        ck = DRexCheckpointer(fabric, "drex_sc", CheckpointPolicy(item_mb=0.25))
-        dc = DataConfig(vocab_size=cfg.vocab_size, seq_len=32, global_batch=4)
+        """Kill-and-restart: a run restored from its newest checkpoint
+        continues exactly like an uninterrupted one."""
+        ck = DRexCheckpointer(small_fabric(), "drex_sc", CheckpointPolicy(item_mb=0.25))
+        job = TrainingClient(ck, every=3)
+        job.run(job.init(), 0, 7)  # dies after step 7
+        assert sorted(ck._manifests) == [3, 6]
 
-        t1 = Trainer(cfg, AdamWConfig(), TrainerConfig(steps=6, log_every=2, ckpt_every=3, async_ckpt=False),
-                     data_cfg=dc, checkpointer=None, log_fn=lambda s, m: None)
-        state = t1.init_or_restore()
-        # wire the checkpointer manually so restore_latest has a like-state
-        like = state
-
-        class Adapter:
-            def save(self, st, step):
-                ck.save(st, step)
-
-            def save_async(self, st, step):
-                return ck.save_async(st, step)
-
-            def restore_latest(self, _cfg):
-                r = ck.restore_latest(like)
-                return r
-
-        t1.checkpointer = Adapter()
-        state = t1.run(state)
-        assert max(ck._manifests) == 6
-
-        # a "failed" trainer restarts and resumes from step 6
-        t2 = Trainer(cfg, AdamWConfig(), TrainerConfig(steps=8, log_every=2),
-                     data_cfg=dc, checkpointer=Adapter(), log_fn=lambda s, m: None)
-        resumed = t2.init_or_restore()
-        assert t2.start_step == 6
-        assert states_equal(resumed, state)
+        # a fresh client restarts and resumes from step 6
+        restarted = TrainingClient(ck, every=3)
+        resumed, step = restarted.resume()
+        assert step == 6
+        assert states_equal(resumed, TrainingClient().run(job.init(), 0, 6))
+        continued = restarted.run(resumed, step, 10)
+        uninterrupted = TrainingClient().run(job.init(), 0, 10)
+        assert int(continued["opt"]["step"]) == 10
+        assert states_equal(continued, uninterrupted)
 
     def test_elastic_restore_onto_new_mesh(self):
-        cfg = get_config("yi_6b", smoke=True)
-        state = init_train_state(cfg, jax.random.PRNGKey(0))
+        state = tiny_state()
         ck = DRexCheckpointer(small_fabric(), "drex_sc", CheckpointPolicy(item_mb=0.25))
         ck.save(state, 1)
         restored, _ = ck.restore_latest(state)
-        from repro.train.step import reshard_state
 
         mesh = make_local_mesh(1, 1)  # "new" cluster shape
-        resharded = reshard_state(restored, cfg, mesh)
+
+        def place(x):
+            spec = PartitionSpec("data") if x.ndim else PartitionSpec()
+            return jax.device_put(x, NamedSharding(mesh, spec))
+
+        resharded = jax.tree.map(place, restored)
+        for leaf in jax.tree.leaves(resharded):
+            assert isinstance(leaf, jax.Array) and leaf.sharding.mesh == mesh
         assert states_equal(state, resharded)
